@@ -16,9 +16,10 @@ Two questions, one benchmark module:
   dead-letters, merge bit-identical to a clean reference). The
   ``--chaos`` CLI mode (what CI runs as its own step) additionally
   scripts one SIGTERM mid-campaign via
-  ``CampaignConfig.kill_after_commits`` in a subprocess, then resumes
-  the journal directory in-process and asserts the finished result is
-  bit-for-bit the uninterrupted one.
+  ``CampaignConfig.kill_after_commits`` in a subprocess — started
+  before the parent touches JAX, so the child can hold the device —
+  then resumes the journal directory in-process and asserts the
+  finished result is bit-for-bit the uninterrupted one.
 
 Registered in `benchmarks.run` as opt-in module ``soak``:
 
@@ -208,17 +209,35 @@ def _child_kill(dir_: str, n: int) -> None:
 
 
 def run_chaos(base_dir: str) -> List[Row]:
-    """The CI chaos step: transient-fault campaign + one SIGTERM/resume
-    cycle. Journal directories live under ``base_dir`` so a failing CI
-    run can upload them as artifacts. Raises on any lost run or
-    non-identical merge."""
-    from repro.core import executor, supervisor
+    """The CI chaos step: one SIGTERM/resume cycle + transient-fault
+    campaign. Journal directories live under ``base_dir`` so a failing
+    CI run can upload them as artifacts. Raises on any lost run or
+    non-identical merge.
 
+    The SIGTERM child runs first, while this process is still off JAX:
+    a device belongs to one process at a time, so a child started after
+    the parent had touched JAX could not get the chip."""
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "run_chaos must start before this process touches JAX: its "
+            "SIGTERM child needs the device")
     base = Path(base_dir)
     base.mkdir(parents=True, exist_ok=True)
     out: List[Row] = []
 
-    # phase 1: 10% of chunks fault transiently — complete with zero lost
+    # phase 1: SIGTERM mid-campaign (subprocess), resumed below
+    d2 = base / "sigterm"
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmarks.campaign_soak",
+         "--child-kill", str(d2), "--n", str(N_CHAOS)],
+        capture_output=True, text=True, timeout=600)
+    killed = proc.returncode in (-signal.SIGTERM, 128 + signal.SIGTERM)
+
+    from repro.core import executor, supervisor
+
+    # phase 2: 10% of chunks fault transiently — complete with zero lost
     d1 = base / "faults"
     merged, report, ref = _chaos_campaign(d1, N_CHAOS)
     ok1 = _identical(ref, merged) and not report.dead
@@ -226,13 +245,7 @@ def run_chaos(base_dir: str) -> List[Row]:
                 f"retries={report.retries};dead={len(report.dead)};"
                 f"identical={_identical(ref, merged)}"))
 
-    # phase 2: SIGTERM mid-campaign (subprocess), resume here
-    d2 = base / "sigterm"
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.campaign_soak",
-         "--child-kill", str(d2), "--n", str(N_CHAOS)],
-        capture_output=True, text=True, timeout=600)
-    killed = proc.returncode in (-signal.SIGTERM, 128 + signal.SIGTERM)
+    # resume the killed campaign here, against a bare reference
     rows, shared = _grid(N_CHAOS)
     ref2, _ = executor.run_grid(_engine, rows, shared, N_CHAOS,
                                 chunk_size=CHUNK_CHAOS)

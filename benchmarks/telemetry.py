@@ -14,9 +14,9 @@ Entries (each with first-call and warm wall time plus runs/sec):
   tick) at 10k mixed-policy tenants; runs/sec is tenant-ticks/sec.
 * ``sweep_throughput`` — the headline metric: warm runs/sec of one
   summary-mode PI grid through each execution layout (one-shot scan,
-  chunked+donated scan, typed-PI scan, chunked scan sharded over 2
-  forced host devices in a subprocess, and the Pallas closed-loop
-  kernel in interpret mode on a reduced grid). ``improvement`` is
+  chunked+donated scan, typed-PI scan, the scan sharded over every local
+  device when there are several, and the Pallas closed-loop kernel —
+  in interpret mode on a reduced grid off the chip). ``improvement`` is
   best-alternative vs one-shot.
 
 "cold" is the first in-process call: with a warm persistent XLA cache it
@@ -35,10 +35,7 @@ benchmark loudly)."""
 from __future__ import annotations
 
 import json
-import os
 import platform
-import subprocess
-import sys
 import time
 from pathlib import Path
 
@@ -153,7 +150,7 @@ def _sweep_throughput(quick: bool = True) -> dict:
     layout (`repro.core.sim.sweep` backends / `repro.core.executor`).
     The grid is identical across layouts, so the ratios are honest; the
     recorded ``improvement`` is best-alternative vs the one-shot scan
-    engine. The Pallas kernel rides a reduced grid off-TPU — the
+    engine. Off the chip the Pallas kernel rides a reduced grid — the
     interpreter executes the kernel body op by op, so its number is a
     correctness-path record, not a horse race."""
     import jax
@@ -178,18 +175,24 @@ def _sweep_throughput(quick: bool = True) -> dict:
         return {"warm_s": round(warm, 4),
                 "runs_per_sec": round(n / max(warm, 1e-9), 2)}
 
+    scan = {"backend": "scan"}
     backends = {
-        "scan_oneshot": timed({}, n_runs),
-        "scan_chunked": timed({"chunk_size": chunk}, n_runs),
-        "scan_typed_pi": timed({"typed_pi": True}, n_runs),
+        "scan_oneshot": timed(scan, n_runs),
+        "scan_chunked": timed({**scan, "chunk_size": chunk}, n_runs),
+        "scan_typed_pi": timed({**scan, "typed_pi": True}, n_runs),
     }
-    # sharded: ONE chunk split across both devices — chunking pays its
-    # dispatch cost only when it buys memory or parallelism, so the
-    # sharded entry uses the layout that buys parallelism
-    sharded = _sharded_subprocess(eps, seeds, n_runs, kw)
-    if sharded is not None:
-        backends["scan_sharded_2dev"] = sharded
-    if quick:
+    # sharded: ONE chunk split across every local device — chunking pays
+    # its dispatch cost only when it buys memory or parallelism, so the
+    # sharded entry uses the layout that buys parallelism. The device
+    # count is fixed when JAX starts, so one device means no such row.
+    n_dev = len(jax.local_devices())
+    if n_dev > 1:
+        backends[f"scan_sharded_{n_dev}dev"] = timed(
+            {**scan, "chunk_size": n_runs, "devices": "all"}, n_runs)
+    native = jax.default_backend() == "tpu"
+    if native:
+        backends["pallas_native"] = timed({"backend": "pallas"}, n_runs)
+    elif quick:
         # reduced grid: interpret mode is the correctness path on CPU
         pallas_seeds = 4
         pk = dict(kw)
@@ -213,47 +216,10 @@ def _sweep_throughput(quick: bool = True) -> dict:
             "warm_s": alts[best]["warm_s"],
             "runs_per_sec": alts[best]["runs_per_sec"],
             "best": best,
+            "devices": n_dev,
             "improvement": round(alts[best]["runs_per_sec"]
                                  / max(one["runs_per_sec"], 1e-9), 3),
             "backends": backends}
-
-
-def _sharded_subprocess(eps, seeds, chunk, kw) -> dict | None:
-    """Warm-time the chunked sweep across 2 forced host CPU devices.
-    Device count is fixed at jax init, so this runs in a subprocess
-    (sharing the persistent XLA cache); None when unavailable."""
-    if (os.cpu_count() or 1) < 2:
-        return None
-    code = f"""
-import json, time
-import jax
-from repro.core.sim import enable_compilation_cache, sweep
-enable_compilation_cache()
-kw = dict(total_work={kw['total_work']}, max_time={kw['max_time']},
-          collect_traces=False, chunk_size={chunk}, devices="all")
-fn = lambda: sweep("gros", {tuple(eps)}, range({seeds}), **kw).exec_time
-jax.block_until_ready(fn())
-t0 = time.time()
-jax.block_until_ready(fn())
-print(json.dumps({{"warm_s": round(time.time() - t0, 4)}}))
-"""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=2")
-    root = Path(__file__).resolve().parents[1]
-    env["PYTHONPATH"] = (str(root / "src") + os.pathsep
-                         + env.get("PYTHONPATH", ""))
-    try:
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True,
-                             timeout=900, cwd=root)
-        warm = json.loads(out.stdout.strip().splitlines()[-1])["warm_s"]
-    except Exception:
-        return None
-    n = len(eps) * seeds
-    return {"warm_s": warm,
-            "runs_per_sec": round(n / max(warm, 1e-9), 2),
-            "note": "subprocess, 2 forced host devices"}
 
 
 def _read_bench() -> dict:

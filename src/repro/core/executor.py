@@ -13,9 +13,10 @@ grid and the hardware:
   call (`donate_argnums`), so XLA reuses them for outputs instead of
   holding both generations live between chunks.
 * **Sharding** — with more than one device, tiles are split across
-  devices via `pmap` (single-device fallback is a plain `jit`); per-run
-  results are identical either way because every run's parameters and
-  RNG stream ride in its own row.
+  devices via `pmap` (single-device fallback is a plain `jit`; an engine
+  that jits itself, like the Pallas op, gets one slice per device);
+  per-run results are identical either way because every run's
+  parameters and RNG stream ride in its own row.
 * **Streaming merge** — per-chunk outputs land in preallocated host
   buffers (or go straight to a ``consume`` callback, e.g. the
   offline-RL transition harvester, and are dropped), so summary
@@ -97,14 +98,14 @@ _COMPILED: dict = {}
 def _compiled(fn: Callable, n_shared: int, devs: Tuple, donate: bool,
               wrap: str) -> Callable:
     """jit/pmap wrapper for the per-chunk engine, cached per (fn,
-    device set, donation). ``wrap='none'`` passes fn through untouched
+    device set, donation). ``wrap='none'`` leaves compilation to fn
     (engines that jit internally, e.g. the Pallas op's static-shape
-    wrapper)."""
+    wrapper) and only splits the chunk over several devices."""
     key = (fn, devs, donate, wrap)
     if key in _COMPILED:
         return _COMPILED[key]
     if wrap == "none":
-        wrapped = fn
+        wrapped = _per_device(fn, devs) if devs else fn
     elif devs:
         inner = jax.pmap(fn, in_axes=(0,) + (None,) * n_shared,
                          devices=devs,
@@ -119,6 +120,25 @@ def _compiled(fn: Callable, n_shared: int, devs: Tuple, donate: bool,
     else:
         wrapped = jax.jit(fn, donate_argnums=(0,) if donate else ())
     _COMPILED[key] = wrapped
+    return wrapped
+
+
+def _per_device(fn: Callable, devs: Tuple) -> Callable:
+    """Shard a chunk for an engine that jits itself (``wrap='none'``):
+    one contiguous slice per device, committed there so the engine's
+    own jit runs on it. Dispatch is asynchronous, so the devices
+    compute their slices at once; the outputs merge on the host."""
+
+    def wrapped(batched, *shared):
+        c = jax.tree_util.tree_leaves(batched)[0].shape[0]
+        per = c // len(devs)
+        outs = [fn(jax.tree_util.tree_map(
+                    lambda x: jax.device_put(x[i * per:(i + 1) * per], d),
+                    batched), *shared)
+                for i, d in enumerate(devs)]
+        return jax.tree_util.tree_map(lambda *xs: np.concatenate(xs),
+                                      *jax.device_get(outs))
+
     return wrapped
 
 

@@ -81,15 +81,19 @@ from repro.core.workloads.schedule import (PhaseSchedule, ScheduleValues,
 logger = logging.getLogger("repro.core.sim")
 
 
-def enable_compilation_cache(path: Optional[str] = None) -> None:
-    """Point XLA's persistent compilation cache at a repo-local dir so the
-    scan engine compiles once per machine, not once per process. Called by
-    tests/conftest.py and benchmarks/run.py; override the location with
-    $REPRO_XLA_CACHE. Safe to call repeatedly."""
-    path = path or os.environ.get("REPRO_XLA_CACHE") or str(
-        Path(__file__).resolve().parents[3] / "experiments" / "xla_cache")
-    Path(path).mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
+def enable_compilation_cache() -> None:
+    """Turn on XLA's persistent compilation cache so the scan engine
+    compiles once per machine, not once per process. Where
+    $JAX_COMPILATION_CACHE_DIR is set, JAX keeps the cache there and no
+    other location is set here; otherwise it is the checkout's fixed
+    ``experiments/xla_cache`` (the path is part of the cache key, so it
+    never moves). Called by tests/conftest.py, benchmarks/run.py and
+    chip_smoke.py. Safe to call repeatedly."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        path = Path(__file__).resolve().parents[3] / "experiments" \
+            / "xla_cache"
+        path.mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", str(path))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
@@ -1163,7 +1167,7 @@ def _sweep_impl(profiles: Union[str, PlantProfile,
                               Sequence[flt.FaultSchedule]] = None,
                 guard: Union[None, bool, flt.GuardConfig] = None,
                 record_events: Union[None, bool, int] = None,
-                backend: str = "scan",
+                backend: str = "auto",
                 chunk_size: Optional[int] = None,
                 devices=None,
                 typed_pi: bool = False,
@@ -1346,10 +1350,8 @@ def _sweep_impl(profiles: Union[str, PlantProfile,
                                np.asarray(x),
                                (n_runs,) + np.shape(x)).copy()), fv)
         if backend == "pallas":
-            if executor.resolve_devices(devices):
-                logger.warning("backend='pallas' runs single-device; "
-                               "ignoring devices=%r", devices)
-                devices = None
+            # the op jits itself; with devices= the executor places one
+            # slice of every chunk on each device
             fn = _flat_core_pallas(collect_traces)
             shared = (float(total_work), float(max_time), float(dt),
                       float(summary_warmup))
@@ -1373,6 +1375,13 @@ def _sweep_impl(profiles: Union[str, PlantProfile,
                 chunk_size=chunk_size, devices=devices, wrap=wrap,
                 consume=consume, config=campaign)
             exec_state = report.state
+            if report.dead:
+                # dead-lettered chunks leave their rows unfilled: a
+                # result assembled from them would be garbage
+                raise RuntimeError(
+                    f"campaign {durable}: chunks {report.dead} were "
+                    "dead-lettered, so their runs were never computed "
+                    "(the journal there records each error)")
         else:
             merged, exec_state = executor.run_grid(
                 fn, batched, shared, n_runs, chunk_size=chunk_size,
@@ -1429,7 +1438,7 @@ def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,
           collect_traces=True, summary_warmup=0, workloads=None,
           detector=None, faults=None, guard=None,
           record_events=None, *,
-          backend: str = "scan",
+          backend: str = "auto",
           chunk_size: Optional[int] = None, devices=None,
           typed_pi: bool = False, consume=None,
           durable=None, campaign=None
@@ -1486,19 +1495,22 @@ def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,
     the same None-leaves contract.
 
     Execution layer (`repro.core.executor`): with every keyword at its
-    default the grid runs ONE-SHOT on the legacy nested-vmap engine —
-    bit-for-bit the pre-executor `sweep`. ``chunk_size=`` cuts the
-    flattened grid into bounded-memory tiles (buffer donation between
-    tiles, streaming merge on host — a 1M-run summary grid no longer
-    has to fit in one vmap); ``devices=`` ("all", an int, or a device
+    default, a grid the scan engine runs goes ONE-SHOT through the
+    legacy nested-vmap engine — bit-for-bit the pre-executor `sweep`.
+    ``chunk_size=`` cuts the flattened grid into bounded-memory tiles
+    (buffer donation between tiles, streaming merge on host — a 1M-run
+    summary grid no longer has to fit in one vmap); ``devices=`` ("all",
+    an int, or a device
     list) shards tiles across devices via pmap with a single-device
     fallback; per-run results are identical in every configuration
     because each run's parameters and RNG stream ride in its own row.
     ``backend="pallas"`` dispatches to the fused closed-loop Pallas
     mega-kernel (`repro.kernels.closed_loop`; fixed-gain PI, static
-    plant, no detector — same model, its own per-run noise stream);
-    ``backend="auto"`` picks the kernel when the grid is capable and
-    the backend lowers it natively (TPU), else scan. ``typed_pi=``
+    plant, no detector — same model, its own per-run noise stream;
+    with ``devices=`` each chunk is split over the devices). The
+    default ``backend="auto"`` picks the kernel when the grid is
+    capable and the platform compiles it natively (TPU), else scan.
+    ``typed_pi=``
     switches the single-branch PI engine to the typed-PIState carry
     (bit-for-bit the packed path; kept as a measured fast-path toggle).
     ``consume=`` streams per-chunk results to a callback ``consume(lo,
@@ -1545,7 +1557,7 @@ def sweep_resumable(profiles, epsilons, seeds, total_work,
                     adaptive=None, policies=None, collect_traces=True,
                     summary_warmup=0, workloads=None, detector=None,
                     faults=None, guard=None, record_events=None, *,
-                    backend: str = "scan", chunk_size: int,
+                    backend: str = "auto", chunk_size: int,
                     devices=None, typed_pi: bool = False, state=None,
                     stop_after: Optional[int] = None):
     """Chunked sweep that can stop and resume ACROSS chunk boundaries:

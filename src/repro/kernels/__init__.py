@@ -14,8 +14,9 @@ these serve the framework's model substrate (DESIGN.md §7) and, with
   online reductions) fused into one kernel, blocked over the run batch
   with the carry resident in VMEM — the same shape of computation as the
   selective scan (serial over time, parallel over lanes), applied to the
-  paper's sweep engine. `repro.core.sim.sweep(backend="pallas")`
-  dispatches to it through the chunked executor.
+  paper's sweep engine. `repro.core.sim.sweep` dispatches capable
+  grids to it on a TPU (``backend="auto"``, the default) through the
+  chunked executor.
 
 Each kernel ships ``kernel.py`` (pl.pallas_call + BlockSpec), ``ops.py``
 (jit'd public wrapper, interpret-mode switch) and ``ref.py`` (pure-jnp

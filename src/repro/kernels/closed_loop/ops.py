@@ -14,8 +14,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.closed_loop import ref as R
-from repro.kernels.closed_loop.kernel import closed_loop_pallas, \
-    unpack_final
+from repro.kernels.closed_loop.kernel import (HIST_KEYS,
+                                             closed_loop_pallas,
+                                             unpack_final)
+from repro.obs import metrics as obs_metrics
 
 
 def draw_noise(keys: jax.Array, T: int) -> jax.Array:
@@ -43,48 +45,63 @@ def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
+def runs_counter():
+    """``closed_loop_runs_total{path}``: runs handed to `closed_loop_sim`
+    by path — ``native`` (compiled kernel), ``interpret`` or ``ref``."""
+    return obs_metrics.get_registry().counter(
+        "closed_loop_runs_total",
+        "runs dispatched to the closed-loop kernel op, by path",
+        labelnames=("path",))
+
+
+def _per_run(final):
+    """Lane-major final carry ((1, B) rows, (BINS, B) histograms) -> the
+    per-run dict of (B,) leaves and (B, BINS) histograms."""
+    return {k: (v.T if k in HIST_KEYS else v[0]) for k, v in final.items()}
+
+
 @functools.partial(jax.jit, static_argnames=("T", "collect", "block_b",
                                              "chunk_t", "interpret",
                                              "use_ref"))
 def _run(prof, gains, keys, scalars, *, T: int, collect: bool,
          block_b: int, chunk_t: int, interpret: bool, use_ref: bool):
     noise = draw_noise(keys, T)
+    prof, gains = prof.T, gains.T  # runs onto the lane axis
     if use_ref:
-        return R.closed_loop_ref(prof, gains, noise, scalars[0],
-                                 scalars[1], scalars[2], scalars[3],
-                                 collect=collect)
-    traces, (state, phist, chist) = closed_loop_pallas(
-        prof, gains, noise, scalars, collect=collect, block_b=block_b,
-        chunk_t=chunk_t, interpret=interpret)
-    return traces, unpack_final(state, phist, chist)
+        traces, final = R.closed_loop_ref(
+            prof, gains, noise, scalars[0], scalars[1], scalars[2],
+            scalars[3], collect=collect)
+    else:
+        traces, blocks = closed_loop_pallas(
+            prof, gains, noise, scalars, collect=collect, block_b=block_b,
+            chunk_t=chunk_t, interpret=interpret)
+        final = unpack_final(*blocks)
+    return traces, _per_run(final)
 
 
 def closed_loop_sim(prof, gains, keys, *, total_work, max_time,
                     dt: float = 1.0, summary_from: float = 0.0,
                     collect: bool = True, block_b: int = 128,
-                    chunk_t: int = 64, interpret=None,
-                    use_ref: bool = False):
+                    chunk_t: int = 64, use_ref: bool = False):
     """Fused closed-loop runs for a flat batch.
 
     prof (B, 14) / gains (B, 9) packed rows, keys (B, 2) PRNG keys ->
     (traces | None, final): traces are (T, B) f32 per `ref.TRACE_KEYS`
     with T = ceil(max_time / dt) (rounded up to the kernel's time
-    chunk), final the `ref` carry dict of (B,) leaves + histograms.
-    ``interpret`` defaults to True off-TPU (CPU CI runs the same kernel
-    body through the Pallas interpreter); ``use_ref=True`` swaps in the
-    jnp oracle — same contract, no Pallas — for A/B tests and as the
-    fallback where even interpret mode is unavailable.
+    chunk), final the per-run carry dict of (B,) leaves + (B, BINS)
+    histograms. The kernel is compiled natively on a TPU and runs
+    through the Pallas interpreter on any other platform (CPU CI runs
+    the same kernel body); there is no way to interpret it on the chip.
+    ``use_ref=True`` swaps in the jnp oracle — same contract, no Pallas
+    — for A/B tests.
+
+    The batch is padded with replicas of its first run up to whole run
+    tiles: 128 lanes on the chip (8 elsewhere), a single tile when the
+    batch is smaller than ``block_b``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = jax.default_backend() != "tpu"
     B = prof.shape[0]
-    # shrink the run tile rather than pad half a tile of replica runs:
-    # a batch just past a block boundary keeps pad waste under half a
-    # (possibly narrowed) tile instead of simulating up to block_b-1
-    # dead rows for the whole horizon
-    block_b = min(block_b, _round_up(B, 8))
-    while block_b > 8 and _round_up(B, block_b) - B > block_b // 2:
-        block_b //= 2
+    block_b = min(block_b, _round_up(B, 8 if interpret else 128))
     Bp = _round_up(B, block_b)
     T = _round_up(int(-(-max_time // dt)), chunk_t)
     pad = Bp - B
@@ -94,11 +111,13 @@ def closed_loop_sim(prof, gains, keys, *, total_work, max_time,
         prof, gains, keys = rep(prof), rep(gains), rep(keys)
     scalars = jnp.asarray([total_work, max_time, dt, summary_from],
                           jnp.float32)
+    runs_counter().inc(B, path="ref" if use_ref else
+                       "interpret" if interpret else "native")
     traces, final = _run(jnp.asarray(prof, jnp.float32),
                          jnp.asarray(gains, jnp.float32),
                          jnp.asarray(keys), scalars, T=T,
                          collect=collect, block_b=block_b,
-                         chunk_t=chunk_t, interpret=bool(interpret),
+                         chunk_t=chunk_t, interpret=interpret,
                          use_ref=bool(use_ref))
     if pad:
         traces = None if traces is None else {k: v[:, :B]

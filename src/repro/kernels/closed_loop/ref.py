@@ -19,6 +19,13 @@ Two deliberate differences from the scan engine, shared with kernel.py:
   ``(T, 5, B)`` tensor of unit normals/uniforms per run key (see
   `ops.draw_noise`) — channels: progress noise z, power noise z, drop
   enter u, drop exit u, heartbeat z.
+
+Layout: runs ride the LANE (last) axis everywhere. Packed parameters are
+``(N_PROF, B)`` / ``(N_GAIN, B)``, so one field is a ``(1, B)`` row;
+every carry leaf is a ``(1, B)`` row and the histograms are
+``(BINS, B)``. The TPU kernel runs this same `step` on ``(., block_b)``
+tiles, and Mosaic lays every per-run vector out the same way (one
+sublane row of lanes) — no sublane/lane mixing, no in-kernel transpose.
 * **Heartbeat counts use `heartbeat_count`** — a rounded-Gaussian
   approximation of the engine's Poisson draw (exact in distribution to
   O(1/sqrt(lam)); the paper-scale rates are 10-80 beats/period where
@@ -81,24 +88,40 @@ def hist_index(x, lo, hi, nbins):
                     0, nbins - 1)
 
 
+def _row(packed, index, name):
+    """One packed field as a (1, B) row."""
+    i = index[name]
+    return packed[i:i + 1]
+
+
+def _mask(cond):
+    """Boolean -> 0/1 float."""
+    return jnp.where(cond, 1.0, 0.0)
+
+
+def _one_hot_add(hist, idx, w):
+    """hist (BINS, B) + w at row idx of each lane (idx, w: (1, B))."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, hist.shape, 0)
+    return hist + jnp.where(rows == idx, w, 0.0)
+
+
 def init_state(prof, gains):
-    """Fresh per-run carry from packed (B, 14) profile and (B, 9) gain
-    rows — `sim._default_init` for the PI branch, as a dict of (B,)
-    arrays (plus the two (B, BINS) histograms)."""
-    B = prof.shape[0]
-    z = jnp.zeros((B,), jnp.float32)
-    pcap0 = prof[:, F["pcap_max"]]
+    """Fresh per-run carry from packed (N_PROF, B) profile and (N_GAIN, B)
+    gain rows — `sim._default_init` for the PI branch, as a dict of
+    (1, B) rows (plus the two (BINS, B) histograms)."""
+    p = lambda name: _row(prof, F, name)
+    g = lambda name: _row(gains, G, name)
+    B = prof.shape[1]
+    z = jnp.zeros((1, B), jnp.float32)
+    pcap0 = p("pcap_max")
     # plant_init: progress_l0 = static_progress(pcap_max) - K_L
     #           = K_L * pcap_linearize(pcap_max)  (plant transform)
-    pl0 = -jnp.exp(-prof[:, F["alpha"]]
-                   * (prof[:, F["a"]] * pcap0 + prof[:, F["b"]]
-                      - prof[:, F["beta"]]))
+    pl0 = -jnp.exp(-p("alpha") * (p("a") * pcap0 + p("b") - p("beta")))
     # pi_init: prev_pcap_l anchored at the GAIN transform's pcap_max
-    gl0 = -jnp.exp(-gains[:, G["alpha"]]
-                   * (gains[:, G["a"]] * gains[:, G["pcap_max"]]
-                      + gains[:, G["b"]] - gains[:, G["beta"]]))
+    gl0 = -jnp.exp(-g("alpha") * (g("a") * g("pcap_max") + g("b")
+                                  - g("beta")))
     return {
-        "progress_l": prof[:, F["K_L"]] * pl0,
+        "progress_l": p("K_L") * pl0,
         "dropped": z,
         "energy": z,
         "work": z,
@@ -114,18 +137,18 @@ def init_state(prof, gains):
         "progress_sum": z,
         "progress_sq_sum": z,
         "power_sum": z,
-        "progress_hist": jnp.zeros((B, PROG_BINS), jnp.float32),
-        "pcap_hist": jnp.zeros((B, CAP_BINS), jnp.float32),
+        "progress_hist": jnp.zeros((PROG_BINS, B), jnp.float32),
+        "pcap_hist": jnp.zeros((CAP_BINS, B), jnp.float32),
     }
 
 
 def step(prof, gains, c, noise_s, total_work, max_time, dt, summary_from):
     """One fused control period over a batch of runs — the engine_step
     transcription. ``noise_s`` is this step's (5, B) noise slab.
-    Returns (new_carry, trace_row) with (B,) leaves."""
-    p = lambda name: prof[:, F[name]]
-    g = lambda name: gains[:, G[name]]
-    z_prog, z_pow, u_enter, u_exit, z_hb = (noise_s[i] for i in
+    Returns (new_carry, trace_row) with (1, B) leaves."""
+    p = lambda name: _row(prof, F, name)
+    g = lambda name: _row(gains, G, name)
+    z_prog, z_pow, u_enter, u_exit, z_hb = (noise_s[i:i + 1] for i in
                                             range(N_NOISE))
     done = c["done"]
     live = 1.0 - done
@@ -135,8 +158,8 @@ def step(prof, gains, c, noise_s, total_work, max_time, dt, summary_from):
     pl = -jnp.exp(-p("alpha") * (p("a") * pcap_app + p("b") - p("beta")))
     w = dt / (dt + p("tau"))
     new_pl = p("K_L") * w * pl + (1.0 - w) * c["progress_l"]
-    enter = (u_enter < p("drop_prob")).astype(jnp.float32)
-    exit_ = (u_exit < p("drop_exit_prob")).astype(jnp.float32)
+    enter = _mask(u_enter < p("drop_prob"))
+    exit_ = _mask(u_exit < p("drop_exit_prob"))
     dropped = jnp.where(c["dropped"] > 0, 1.0 - exit_, enter)
     clean = new_pl + p("K_L")
     meas_noise = (p("noise_scale") * jnp.sqrt(p("n_sockets")) * z_prog)
@@ -153,7 +176,7 @@ def step(prof, gains, c, noise_s, total_work, max_time, dt, summary_from):
     progress = window_median(n, c["anchor_gap"], c["has_anchor"] > 0, dt)
     anchor_gap = jnp.where(n > 0, 0.5 * dt / jnp.maximum(n, 1.0),
                            c["anchor_gap"] + dt)
-    has_anchor = jnp.maximum(c["has_anchor"], (n > 0).astype(jnp.float32))
+    has_anchor = jnp.maximum(c["has_anchor"], _mask(n > 0))
 
     # ---- Eq. 4 PI with anti-windup clamp ----------------------------------
     error = g("setpoint") - progress
@@ -183,17 +206,14 @@ def step(prof, gains, c, noise_s, total_work, max_time, dt, summary_from):
     power_out = jnp.where(done > 0, 0.0, power_m)
 
     # ---- online summary reductions ----------------------------------------
-    acc = live * (c["steps"] >= summary_from).astype(jnp.float32)
+    acc = live * _mask(c["steps"] >= summary_from)
     pidx = hist_index(progress, 0.0, PROG_HIST_SPAN * p("K_L"), PROG_BINS)
     cidx = hist_index(pcap_cmd, p("pcap_min"), p("pcap_max"), CAP_BINS)
-    prog_hist = c["progress_hist"] + acc[:, None] * jax.nn.one_hot(
-        pidx, PROG_BINS, dtype=jnp.float32)
-    pcap_hist = c["pcap_hist"] + acc[:, None] * jax.nn.one_hot(
-        cidx, CAP_BINS, dtype=jnp.float32)
+    prog_hist = _one_hot_add(c["progress_hist"], pidx, acc)
+    pcap_hist = _one_hot_add(c["pcap_hist"], cidx, acc)
 
     new_done = jnp.maximum(done, jnp.maximum(
-        (work >= total_work).astype(jnp.float32),
-        (t >= max_time - 1e-6).astype(jnp.float32)))
+        _mask(work >= total_work), _mask(t >= max_time - 1e-6)))
     out = {"t": t, "progress": progress, "pcap": pcap_cmd,
            "power": power_out, "energy": energy, "work": work,
            "valid": live}
@@ -214,12 +234,13 @@ def step(prof, gains, c, noise_s, total_work, max_time, dt, summary_from):
 def closed_loop_ref(prof, gains, noise, total_work, max_time,
                     dt=1.0, summary_from=0.0, collect: bool = True
                     ) -> Tuple[Optional[dict], dict]:
-    """prof (B, 14), gains (B, 9), noise (T, 5, B) -> (traces, final).
+    """prof (N_PROF, B), gains (N_GAIN, B), noise (T, 5, B) ->
+    (traces, final), in the kernel's lane-major layout.
 
     Traces (collect=True) are (T, B) per key in `TRACE_KEYS`; `final` is
-    the full carry dict of (B,) leaves plus the (B, BINS) histograms —
-    the same contract `ops.closed_loop_sim` returns, so the kernel and
-    this oracle are interchangeable in tests.
+    the full carry dict of (1, B) rows plus the (BINS, B) histograms —
+    what `kernel.closed_loop_pallas` computes, so `ops.closed_loop_sim`
+    turns either into the same per-run dict.
     """
     prof = jnp.asarray(prof, jnp.float32)
     gains = jnp.asarray(gains, jnp.float32)
@@ -234,4 +255,6 @@ def closed_loop_ref(prof, gains, noise, total_work, max_time,
         return new, (out if collect else None)
 
     final, traces = jax.lax.scan(body, init_state(prof, gains), noise)
+    if traces is not None:
+        traces = {k: v[:, 0] for k, v in traces.items()}
     return traces, final

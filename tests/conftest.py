@@ -1,11 +1,14 @@
 import jax
 import pytest
 
-# Smoke tests and benches must see the real single CPU device — the 512
-# placeholder devices are ONLY for the dry-run (see launch/dryrun.py).
-jax.config.update("jax_platform_name", "cpu")
+# The suite runs on the CPU, with Pallas kernels in interpret mode; the
+# 512 placeholder devices are ONLY for the dry-run (see
+# launch/dryrun.py). `tests/test_tpu_compile.py` compiles for a
+# described chip without attaching one.
+jax.config.update("jax_platforms", "cpu")
 
 # Share compiled scan engines across processes (and with benchmarks/run.py)
+# in $JAX_COMPILATION_CACHE_DIR, else experiments/xla_cache
 from repro.core.sim import enable_compilation_cache  # noqa: E402
 
 enable_compilation_cache()

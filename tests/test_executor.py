@@ -109,16 +109,14 @@ def test_resume_across_chunk_boundary_round_trips():
                         **KW)
 
 
-def test_sharded_equals_single_device():
-    """Chunks shard across devices via pmap; per-run results must be
-    identical. Runs in a subprocess with 2 forced host CPU devices
-    (device count is fixed at jax init)."""
-    code = """
+def _sharded_matches_single_device(backend):
+    code = f"""
 import numpy as np
 from repro.core.sim import sweep
 import jax
 assert len(jax.local_devices()) == 2, jax.local_devices()
-kw = dict(total_work=300.0, max_time=256.0, collect_traces=False)
+kw = dict(total_work=300.0, max_time=256.0, collect_traces=False,
+          backend={backend!r})
 one = sweep("gros", [0.1, 0.3], range(4), **kw)
 sh = sweep("gros", [0.1, 0.3], range(4), chunk_size=4, devices="all", **kw)
 np.testing.assert_array_equal(np.asarray(one.exec_time), np.asarray(sh.exec_time))
@@ -133,6 +131,20 @@ print("SHARDED_OK")
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "SHARDED_OK" in out.stdout, out.stdout + out.stderr
+
+
+def test_sharded_equals_single_device():
+    """Chunks shard across devices via pmap; per-run results must be
+    identical. Runs in a subprocess with 2 forced host CPU devices
+    (device count is fixed at jax init)."""
+    _sharded_matches_single_device("scan")
+
+
+def test_pallas_sharded_equals_single_device():
+    """The Pallas op jits itself, so the executor commits one slice of
+    each chunk to each device instead of pmapping it; per-run results
+    must still be identical to one device."""
+    _sharded_matches_single_device("pallas")
 
 
 def test_run_grid_consume_and_stop_semantics():

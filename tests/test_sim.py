@@ -388,3 +388,27 @@ def test_replay_model_matches_reference_loop():
         y = p.K_L * w * pl[i] + (1 - w) * y
         ref[i] = y + p.K_L
     np.testing.assert_allclose(pred, ref, rtol=1e-5)
+
+
+def test_compilation_cache_location(monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR owns the cache location when set;
+    otherwise it is the checkout's fixed experiments/xla_cache."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.core.sim import enable_compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", "set-by-jax")
+        enable_compilation_cache()
+        assert jax.config.jax_compilation_cache_dir == "set-by-jax"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        enable_compilation_cache()
+        root = Path(__file__).resolve().parents[1]
+        assert jax.config.jax_compilation_cache_dir == str(
+            root / "experiments" / "xla_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -165,6 +165,29 @@ def test_permanent_failure_dead_letters_and_campaign_continues(tmp_path):
         np.testing.assert_array_equal(got[2 * CHUNK:], want[2 * CHUNK:])
 
 
+def test_sweep_refuses_a_result_with_dead_lettered_chunks(tmp_path,
+                                                           monkeypatch):
+    """A dead-lettered chunk's rows were never computed: `sweep` raises
+    instead of returning them as results."""
+    from repro.core import sim
+
+    real = executor.run_grid
+    calls = []
+
+    def first_chunk_fails(*a, **kw):
+        calls.append(1)
+        if len(calls) == 1:
+            raise ValueError("bad shapes")
+        return real(*a, **kw)
+
+    monkeypatch.setattr(executor, "run_grid", first_chunk_fails)
+    with pytest.raises(RuntimeError, match="dead-lettered"):
+        sim.sweep("gros", [0.1], range(4), total_work=100.0,
+                  max_time=64.0, collect_traces=False, chunk_size=2,
+                  durable=tmp_path)
+    assert len(calls) == 2  # the campaign went on past the dead chunk
+
+
 def test_retry_budget_exhaustion_dead_letters(tmp_path):
     batched, shared = _grid()
     fails = {i: supervisor.TransientFault(f"attempt {i}")
