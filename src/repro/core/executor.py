@@ -126,8 +126,9 @@ def _compiled(fn: Callable, n_shared: int, devs: Tuple, donate: bool,
 def _per_device(fn: Callable, devs: Tuple) -> Callable:
     """Shard a chunk for an engine that jits itself (``wrap='none'``):
     one contiguous slice per device, committed there so the engine's
-    own jit runs on it. Dispatch is asynchronous, so the devices
-    compute their slices at once; the outputs merge on the host."""
+    own jit runs on it; the outputs merge on the host. The engine
+    compiles once per device here, and no trace has shown the devices
+    computing their slices concurrently."""
 
     def wrapped(batched, *shared):
         c = jax.tree_util.tree_leaves(batched)[0].shape[0]

@@ -1290,9 +1290,13 @@ def _sweep_impl(profiles: Union[str, PlantProfile,
     if backend == "auto":
         # capability dispatch: the mega-kernel covers the flagship
         # fixed-gain PI path and pays off where it lowers natively; the
-        # interpreted kernel is for correctness work, not speed
-        backend = ("pallas" if pallas_ok
+        # interpreted kernel is for correctness work, not speed.
+        # typed_pi= names a carry of the scan engine, so it keeps the scan
+        backend = ("pallas" if pallas_ok and not typed_pi
                    and jax.default_backend() == "tpu" else "scan")
+    elif backend == "pallas" and typed_pi:
+        raise ValueError("typed_pi= is a carry of the scan engine; the "
+                         "Pallas kernel has its own — use backend='scan'")
     elif backend == "pallas" and not pallas_ok:
         raise ValueError(
             "backend='pallas' covers the fixed-gain PI path only "
@@ -1510,9 +1514,17 @@ def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,
     with ``devices=`` each chunk is split over the devices). The
     default ``backend="auto"`` picks the kernel when the grid is
     capable and the platform compiles it natively (TPU), else scan.
+    So on a TPU a fixed-gain PI grid draws from the same model as on a
+    CPU but not the same samples: the kernel has its own noise streams
+    and approximates each step's heartbeat count by a rounded Gaussian
+    where the scan draws a Poisson, which moves per-(plant, epsilon)
+    medians by up to ~2% (ROADMAP D2). Pass ``backend="scan"`` for the
+    CPU's exact samples on any platform.
     ``typed_pi=``
-    switches the single-branch PI engine to the typed-PIState carry
-    (bit-for-bit the packed path; kept as a measured fast-path toggle).
+    switches the single-branch PI scan engine to the typed-PIState carry
+    (bit-for-bit the packed path; kept as a measured fast-path toggle);
+    it keeps ``backend="auto"`` on the scan and is refused with
+    ``backend="pallas"``.
     ``consume=`` streams per-chunk results to a callback ``consume(lo,
     hi, (traces, final))`` instead of accumulating them (the offline-RL
     dataset harvester) — `sweep` then returns None.

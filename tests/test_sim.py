@@ -374,6 +374,10 @@ def test_typed_pi_fast_path_bit_for_bit():
     with pytest.raises(ValueError, match="typed_pi"):
         sweep("gros", [0.1], [0], total_work=100.0,
               adaptive=RLSConfig(), typed_pi=True)
+    # ... and is a scan-engine carry the kernel does not take
+    with pytest.raises(ValueError, match="typed_pi"):
+        sweep("gros", [0.1], [0], total_work=100.0, typed_pi=True,
+              backend="pallas")
 
 
 def test_replay_model_matches_reference_loop():

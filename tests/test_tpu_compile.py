@@ -41,11 +41,9 @@ def one_chip():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # no skip: where libtpu cannot describe the chip, these tests fail
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
