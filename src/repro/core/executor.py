@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import time
 import warnings
 from typing import Any, Callable, Optional, Sequence, Tuple, Union
 
@@ -197,15 +196,18 @@ def run_grid(fn: Callable, batched: Any, shared: Tuple, n_runs: int, *,
     (``stop_after=`` cut the call short — pass the state back in to
     continue across the chunk boundary).
 
-    Observability: chunk/run/resume counters, live progress gauges
-    (``executor_grid_chunks_done`` / ``_planned``) and a runs-per-second
-    gauge publish into the process metrics registry after EVERY chunk —
-    a `repro.obs.serve` scrape endpoint watches a campaign advance
-    mid-call — and when the span
-    tracer is enabled (`repro.obs.trace.enable()`) every chunk emits
-    prepare/compute/transfer/merge spans with device ids — the first
-    chunk of a freshly wrapped engine is marked ``cold`` (its compute
-    span includes XLA compilation)."""
+    Observability: chunk/run/resume counters and live progress gauges
+    (``executor_grid_chunks_done`` / ``_planned``) publish into the
+    process metrics registry after EVERY chunk — a `repro.obs.serve`
+    scrape endpoint watches a campaign advance mid-call — and when the
+    span tracer is enabled (`repro.obs.trace.enable()`) every chunk
+    emits prepare/compute/transfer/merge spans with device ids, nested
+    under the caller's span (``sweep``, ``plane/tick``). The first chunk
+    of a freshly wrapped engine is marked ``cold`` (its compute span
+    includes XLA compilation). Only while tracing does ``compute`` block
+    until the device is done, so a traced run books the device wait
+    under ``executor/compute`` (untraced, dispatch is asynchronous and
+    the wait falls in the transfer's ``device_get``)."""
     chunk = int(chunk_size) if chunk_size else n_runs
     chunk = max(1, min(chunk, n_runs))
     devs = resolve_devices(devices)
@@ -245,8 +247,6 @@ def run_grid(fn: Callable, batched: Any, shared: Tuple, n_runs: int, *,
     # are identical to the old single publication
     c_chunks = reg.counter("executor_chunks_total", "grid chunks executed")
     c_runs = reg.counter("executor_runs_total", "grid runs executed")
-    g_rate = reg.gauge("executor_last_runs_per_sec",
-                       "throughput of the most recent run_grid call")
     g_plan = reg.gauge("executor_grid_chunks_planned",
                        "chunk count of the current run_grid call")
     g_done = reg.gauge("executor_grid_chunks_done",
@@ -255,8 +255,6 @@ def run_grid(fn: Callable, batched: Any, shared: Tuple, n_runs: int, *,
     g_plan.set(n_chunks)
     g_done.set(int(state.done.sum()))
     ran = 0
-    runs_done = 0
-    t0 = time.perf_counter()
     # ONE scoped filter installation around the whole chunk loop (and
     # restored on exit, early returns included): user warning filters
     # are never mutated module-wide, and the hot loop stops
@@ -314,13 +312,9 @@ def run_grid(fn: Callable, batched: Any, shared: Tuple, n_runs: int, *,
                     jax.tree_util.tree_map(fill, state.buffers, out)
             state.done[ci] = True
             ran += 1
-            runs_done += hi - lo
             c_chunks.inc()
             c_runs.inc(hi - lo)
             g_done.set(int(state.done.sum()))
-            elapsed = time.perf_counter() - t0
-            if elapsed > 0:
-                g_rate.set(runs_done / elapsed)
     if stopped:
         return None, state
     merged = state.buffers if (consume is None and state.complete) \

@@ -53,6 +53,7 @@ from repro.core.policies.pi import PI_RLS_HI, PI_RLS_LO, PIPolicy, pi_pack
 from repro.core.signals import TenantHeartbeatStore
 from repro.obs import events as evt
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 from repro.core.workloads.detect import (DET_PARAM_DIM, DET_STATE_DIM,
                                          DetectorConfig, detect_init,
                                          detect_step, detector_values)
@@ -265,13 +266,13 @@ def tick_fn(branches: Tuple[str, ...], guarded: bool = False) -> Callable:
 
         vrow = jax.vmap(row, in_axes=(0,) * 9 + (None,))
 
-        def fn(rows: Dict[str, jnp.ndarray], dt):
+        def plane_tick(rows: Dict[str, jnp.ndarray], dt):
             return vrow(rows["gains"], rows["pvals"], rows["pstate"],
                         rows["det_vals"], rows["det_state"],
                         rows["det_on"], rows["pcap"], rows["progress"],
                         rows["power"], dt)
 
-        return fn
+        return plane_tick
 
     def grow(gv, pv, ps, dv, ds, det_on, gvv, gst, g_on, pcap_applied,
              progress, power, dt):
@@ -289,14 +290,14 @@ def tick_fn(branches: Tuple[str, ...], guarded: bool = False) -> Callable:
 
     vgrow = jax.vmap(grow, in_axes=(0,) * 12 + (None,))
 
-    def gfn(rows: Dict[str, jnp.ndarray], dt):
+    def plane_tick(rows: Dict[str, jnp.ndarray], dt):
         return vgrow(rows["gains"], rows["pvals"], rows["pstate"],
                      rows["det_vals"], rows["det_state"], rows["det_on"],
                      rows["guard_vals"], rows["guard_state"],
                      rows["guard_on"], rows["pcap"], rows["progress"],
                      rows["power"], dt)
 
-    return gfn
+    return plane_tick
 
 
 def _bucket(n: int, lo: int = 16) -> int:
@@ -582,10 +583,11 @@ class ControlPlane:
         ``tenant_ids`` are the ids returned by add_tenant(s); when they
         are the default slot ints the mapping is the identity and the
         whole batch is one vectorized store append."""
-        ids = np.asarray(tenant_ids)
-        if ids.dtype.kind not in "iu":
-            ids = np.asarray([self._slots[t] for t in ids.tolist()])
-        self.store.ingest(ids, times, works)
+        with obs_trace.get_tracer().span("plane/ingest"):
+            ids = np.asarray(tenant_ids)
+            if ids.dtype.kind not in "iu":
+                ids = np.asarray([self._slots[t] for t in ids.tolist()])
+            self.store.ingest(ids, times, works)
 
     # ---- the tick ---------------------------------------------------------
     def tick(self, dt: Optional[float] = None, now: Optional[float] = None,
@@ -611,100 +613,113 @@ class ControlPlane:
         (`plane_ticks_total`, `plane_tick_seconds`, tenant/quarantine
         gauges, `plane_ingest_drops_total`).
         """
-        t_wall = time.perf_counter()
-        if now is not None:
-            dt = max(now - self._t, 1e-6) if dt is None else dt
-            self._t = now
-        else:
-            dt = self.dt if dt is None else float(dt)
-            self._t += dt
-        cap = self.capacity
-        progress = self.store.progress_all(self._t).astype(np.float32)
-        progress = np.where(self._alive, progress, 0.0)
-        if power is None:
-            pw = np.full(cap, np.nan, np.float32)
-        else:
-            pw = np.asarray(power, np.float32).reshape(-1)
-            if pw.shape != (cap,):
-                full = np.full(cap, np.nan, np.float32)
-                full[:len(pw)] = pw
-                pw = full
-        rows = {"gains": self._gains, "pvals": self._pvals,
-                "pstate": self._pstate, "det_vals": self._dvals,
-                "det_state": self._dstate, "det_on": self._det_on,
-                "pcap": self._pcap, "progress": progress, "power": pw}
-        # the guard rides the tick only when some live tenant armed it:
-        # a guard-free plane keeps running the pre-guard compiled graph
-        guarded = bool(self._guard_on.any())
-        if guarded:
-            rows.update(guard_vals=self._gvals, guard_state=self._gstate,
-                        guard_on=self._guard_on)
-            prev_mode = self._gstate[:, flt.G_MODE].copy()
-        fn = tick_fn(self._branches, guarded)
-        decisions = {"pcap": np.empty(cap, np.float32),
-                     "applied": np.empty(cap, np.float32),
-                     "phase_change": np.empty(cap, np.float32)}
-        if guarded:
-            decisions["guard_mode"] = np.empty(cap, np.float32)
+        tracer = obs_trace.get_tracer()
+        with tracer.span("plane/tick"):
+            t_wall = time.perf_counter()
+            if now is not None:
+                dt = max(now - self._t, 1e-6) if dt is None else dt
+                self._t = now
+            else:
+                dt = self.dt if dt is None else float(dt)
+                self._t += dt
+            cap = self.capacity
+            with tracer.span("plane/aggregate"):
+                progress = self.store.progress_all(self._t).astype(
+                    np.float32)
+            with tracer.span("plane/pack"):
+                progress = np.where(self._alive, progress, 0.0)
+                if power is None:
+                    pw = np.full(cap, np.nan, np.float32)
+                else:
+                    pw = np.asarray(power, np.float32).reshape(-1)
+                    if pw.shape != (cap,):
+                        full = np.full(cap, np.nan, np.float32)
+                        full[:len(pw)] = pw
+                        pw = full
+                rows = {"gains": self._gains, "pvals": self._pvals,
+                        "pstate": self._pstate, "det_vals": self._dvals,
+                        "det_state": self._dstate, "det_on": self._det_on,
+                        "pcap": self._pcap, "progress": progress,
+                        "power": pw}
+                # the guard rides the tick only when some live tenant
+                # armed it: a guard-free plane keeps running the
+                # pre-guard compiled graph
+                guarded = bool(self._guard_on.any())
+                if guarded:
+                    rows.update(guard_vals=self._gvals,
+                                guard_state=self._gstate,
+                                guard_on=self._guard_on)
+                    prev_mode = self._gstate[:, flt.G_MODE].copy()
+                fn = tick_fn(self._branches, guarded)
+                decisions = {"pcap": np.empty(cap, np.float32),
+                             "applied": np.empty(cap, np.float32),
+                             "phase_change": np.empty(cap, np.float32)}
+                if guarded:
+                    decisions["guard_mode"] = np.empty(cap, np.float32)
 
-        def _merge(lo, hi, out):
-            self._pstate[lo:hi] = out["pstate"]
-            self._dstate[lo:hi] = out["det_state"]
-            self._pcap[lo:hi] = out["applied"]
-            if guarded:
-                self._gstate[lo:hi] = out["guard_state"]
-            for k in decisions:
-                decisions[k][lo:hi] = out[k]
-            if consume is not None:
-                consume(lo, hi, {k: out[k] for k in decisions})
+            def _merge(lo, hi, out):
+                self._pstate[lo:hi] = out["pstate"]
+                self._dstate[lo:hi] = out["det_state"]
+                self._pcap[lo:hi] = out["applied"]
+                if guarded:
+                    self._gstate[lo:hi] = out["guard_state"]
+                for k in decisions:
+                    decisions[k][lo:hi] = out[k]
+                if consume is not None:
+                    consume(lo, hi, {k: out[k] for k in decisions})
 
-        executor.run_grid(fn, rows, (jnp.float32(dt),), cap,
-                          chunk_size=chunk_size, devices=devices,
-                          donate=False, consume=_merge)
-        decisions["progress"] = progress
-        self.last = decisions
-        # decision stream: edge-triggered incidents only (np.nonzero over
-        # boolean masks — the common all-healthy tick appends nothing)
-        alarms = (decisions["phase_change"] > 0) & (self._det_on > 0.5) \
-            & self._alive
-        for s in np.nonzero(alarms)[0]:
-            self.events.append(self._t, evt.EV_DETECTOR_ALARM,
-                               evt.SRC_PLANE, (1, int(s)))
-        if guarded:
-            mode = self._gstate[:, flt.G_MODE]
-            armed = (self._guard_on > 0.5) & self._alive
-            q_in = armed & (mode >= flt.GUARD_FAILSAFE) \
-                & (prev_mode < flt.GUARD_FAILSAFE)
-            q_out = armed & (mode < flt.GUARD_FAILSAFE) \
-                & (prev_mode >= flt.GUARD_FAILSAFE)
-            held = armed & (mode >= flt.GUARD_HOLD) \
-                & (prev_mode < flt.GUARD_HOLD)
-            for mask, code in ((held, evt.EV_GUARD_HOLD),
-                               (q_in, evt.EV_QUARANTINE_ENTER),
-                               (q_out, evt.EV_QUARANTINE_EXIT)):
-                for s in np.nonzero(mask)[0]:
-                    self.events.append(self._t, code, evt.SRC_PLANE,
-                                       (1, int(s)))
-        reg = obs_metrics.get_registry()
-        reg.counter("plane_ticks_total",
-                    "control-plane ticks executed").inc()
-        reg.gauge("plane_tenants", "live tenant rows").set(
-            float(self._alive.sum()))
-        n_quar = (float(((self._gstate[:, flt.G_MODE]
-                          >= flt.GUARD_FAILSAFE)
-                         & (self._guard_on > 0.5) & self._alive).sum())
-                  if guarded else 0.0)
-        reg.gauge("plane_quarantined",
-                  "tenants held in guard fail-safe").set(n_quar)
-        drops = float(self.store._drops.sum())
-        if drops > self._drops_published:
-            reg.counter("plane_ingest_drops_total",
-                        "heartbeats rejected by ingest sanitization"
-                        ).inc(drops - self._drops_published)
-            self._drops_published = drops
-        reg.histogram("plane_tick_seconds",
-                      "wall-clock latency of one plane tick").observe(
-            time.perf_counter() - t_wall)
+            executor.run_grid(fn, rows, (jnp.float32(dt),), cap,
+                              chunk_size=chunk_size, devices=devices,
+                              donate=False, consume=_merge)
+            decisions["progress"] = progress
+            self.last = decisions
+            with tracer.span("plane/events"):
+                # decision stream: edge-triggered incidents only
+                # (np.nonzero over boolean masks — the common
+                # all-healthy tick appends nothing)
+                alarms = (decisions["phase_change"] > 0) \
+                    & (self._det_on > 0.5) & self._alive
+                for s in np.nonzero(alarms)[0]:
+                    self.events.append(self._t, evt.EV_DETECTOR_ALARM,
+                                       evt.SRC_PLANE, (1, int(s)))
+                if guarded:
+                    mode = self._gstate[:, flt.G_MODE]
+                    armed = (self._guard_on > 0.5) & self._alive
+                    q_in = armed & (mode >= flt.GUARD_FAILSAFE) \
+                        & (prev_mode < flt.GUARD_FAILSAFE)
+                    q_out = armed & (mode < flt.GUARD_FAILSAFE) \
+                        & (prev_mode >= flt.GUARD_FAILSAFE)
+                    held = armed & (mode >= flt.GUARD_HOLD) \
+                        & (prev_mode < flt.GUARD_HOLD)
+                    for mask, code in ((held, evt.EV_GUARD_HOLD),
+                                       (q_in, evt.EV_QUARANTINE_ENTER),
+                                       (q_out, evt.EV_QUARANTINE_EXIT)):
+                        for s in np.nonzero(mask)[0]:
+                            self.events.append(self._t, code,
+                                               evt.SRC_PLANE, (1, int(s)))
+            with tracer.span("plane/publish"):
+                reg = obs_metrics.get_registry()
+                reg.counter("plane_ticks_total",
+                            "control-plane ticks executed").inc()
+                reg.gauge("plane_tenants", "live tenant rows").set(
+                    float(self._alive.sum()))
+                n_quar = (float(((self._gstate[:, flt.G_MODE]
+                                  >= flt.GUARD_FAILSAFE)
+                                 & (self._guard_on > 0.5)
+                                 & self._alive).sum())
+                          if guarded else 0.0)
+                reg.gauge("plane_quarantined",
+                          "tenants held in guard fail-safe").set(n_quar)
+                drops = float(self.store._drops.sum())
+                if drops > self._drops_published:
+                    reg.counter("plane_ingest_drops_total",
+                                "heartbeats rejected by ingest "
+                                "sanitization"
+                                ).inc(drops - self._drops_published)
+                    self._drops_published = drops
+                reg.histogram("plane_tick_seconds",
+                              "wall-clock latency of one plane tick"
+                              ).observe(time.perf_counter() - t_wall)
         return decisions
 
     def quarantined(self) -> List[Any]:

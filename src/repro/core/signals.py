@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 
 class TenantHeartbeatStore:
@@ -187,37 +188,40 @@ class TenantHeartbeatStore:
         window report 0.0 and keep their buffer untouched.
         """
         N, B = self._t.shape
-        t_i = np.ascontiguousarray(np.broadcast_to(
-            np.asarray(t_i, np.float64), (N,)))
-        col = np.arange(B)[None, :]
-        valid = col < self._n[:, None]
-        in_win = valid & (self._t < t_i[:, None])  # sorted -> a prefix
-        k = in_win.sum(axis=1)
-        prev = np.empty_like(self._t)
-        prev[:, 1:] = self._t[:, :-1]
-        prev[:, 0] = self._anchor                  # nan when unanchored
-        with np.errstate(invalid="ignore", divide="ignore",
-                         over="ignore"):
-            dts = self._t - prev
-            ok = in_win & (dts > 0)                # nan prev -> False
-            rates = np.where(ok, self._w / np.where(ok, dts, 1.0),
-                             np.inf)
-        m = ok.sum(axis=1)
-        srt = np.sort(rates, axis=1)               # valid first, inf pad
-        lo = np.maximum((m - 1) // 2, 0)
-        hi = np.where(m > 0, m // 2, 0)
-        med = 0.5 * (np.take_along_axis(srt, lo[:, None], 1)[:, 0]
-                     + np.take_along_axis(srt, hi[:, None], 1)[:, 0])
-        out = np.where(m > 0, med, 0.0)
-        # consume each non-empty window: newest rated beat -> anchor,
-        # shift the survivors to the row head
-        rows = k > 0
-        last = self._t[np.arange(N), np.maximum(k - 1, 0)]
-        self._anchor = np.where(rows, last, self._anchor)
-        idx = np.minimum(col + k[:, None], B - 1)  # k==0 rows: identity
-        self._t = np.take_along_axis(self._t, idx, 1)
-        self._w = np.take_along_axis(self._w, idx, 1)
-        self._n = self._n - k
+        tracer = obs_trace.get_tracer()
+        with tracer.span("signals/median"):
+            t_i = np.ascontiguousarray(np.broadcast_to(
+                np.asarray(t_i, np.float64), (N,)))
+            col = np.arange(B)[None, :]
+            valid = col < self._n[:, None]
+            in_win = valid & (self._t < t_i[:, None])  # sorted -> prefix
+            k = in_win.sum(axis=1)
+            prev = np.empty_like(self._t)
+            prev[:, 1:] = self._t[:, :-1]
+            prev[:, 0] = self._anchor                  # nan: unanchored
+            with np.errstate(invalid="ignore", divide="ignore",
+                             over="ignore"):
+                dts = self._t - prev
+                ok = in_win & (dts > 0)                # nan prev -> False
+                rates = np.where(ok, self._w / np.where(ok, dts, 1.0),
+                                 np.inf)
+            m = ok.sum(axis=1)
+            srt = np.sort(rates, axis=1)               # valid first, inf pad
+            lo = np.maximum((m - 1) // 2, 0)
+            hi = np.where(m > 0, m // 2, 0)
+            med = 0.5 * (np.take_along_axis(srt, lo[:, None], 1)[:, 0]
+                         + np.take_along_axis(srt, hi[:, None], 1)[:, 0])
+            out = np.where(m > 0, med, 0.0)
+        with tracer.span("signals/shift"):
+            # consume each non-empty window: newest rated beat -> anchor,
+            # shift the survivors to the row head
+            rows = k > 0
+            last = self._t[np.arange(N), np.maximum(k - 1, 0)]
+            self._anchor = np.where(rows, last, self._anchor)
+            idx = np.minimum(col + k[:, None], B - 1)  # k==0 rows: identity
+            self._t = np.take_along_axis(self._t, idx, 1)
+            self._w = np.take_along_axis(self._w, idx, 1)
+            self._n = self._n - k
         self._last_emit = t_i.copy()               # unconditional
         return out
 

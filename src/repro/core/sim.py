@@ -63,6 +63,7 @@ import numpy as np
 from repro.core import faults as flt
 from repro.core import plane
 from repro.obs import events as evt
+from repro.obs import trace as obs_trace
 from repro.core import policies as pol
 from repro.core.adaptive import (RLSConfig, RLSState, rls_init, rls_pack,
                                  rls_unpack, rls_values)
@@ -714,7 +715,7 @@ def _flat_core(max_steps: int, branches, collect: bool, scheduled: bool,
     selects the variant)."""
     run = _scan_core(max_steps, collect, branches, typed_pi, n_events)
 
-    def flat(batched, total_work, max_time, dt, summary_from, *rest):
+    def sweep_scan(batched, total_work, max_time, dt, summary_from, *rest):
         gvl = rest[0] if guarded else None
 
         def one(b):
@@ -725,7 +726,7 @@ def _flat_core(max_steps: int, branches, collect: bool, scheduled: bool,
 
         return jax.vmap(one)(batched)
 
-    return flat
+    return sweep_scan  # the module reads jit_sweep_scan in a profile
 
 
 @functools.lru_cache(maxsize=None)
@@ -1145,172 +1146,233 @@ def simulate_closed_loop(profile: Union[str, PlantProfile],
                                   else np.asarray(final.events)))
 
 
-def _sweep_impl(profiles: Union[str, PlantProfile,
-                                Sequence[Union[str, PlantProfile]]],
-                epsilons: Sequence[float],
-                seeds: Sequence[int],
-                total_work: float,
-                max_time: float = 3600.0,
-                dt: float = 1.0,
-                tau_obj: float = 10.0,
-                adaptive: Union[None, RLSConfig,
-                                Sequence[RLSConfig]] = None,
-                policies: Union[None, pol.Policy,
-                                Sequence[pol.Policy]] = None,
-                collect_traces: bool = True,
-                summary_warmup: int = 0,
-                workloads: Union[None, PhaseSchedule,
-                                 Sequence[PhaseSchedule]] = None,
-                detector: Union[None, DetectorConfig,
-                                Sequence[DetectorConfig]] = None,
-                faults: Union[None, flt.FaultSchedule,
-                              Sequence[flt.FaultSchedule]] = None,
-                guard: Union[None, bool, flt.GuardConfig] = None,
-                record_events: Union[None, bool, int] = None,
-                backend: str = "auto",
-                chunk_size: Optional[int] = None,
-                devices=None,
-                typed_pi: bool = False,
-                consume=None,
-                state=None,
-                stop_after: Optional[int] = None,
-                durable=None,
-                campaign=None):
-    """Shared implementation behind `sweep` / `sweep_resumable`:
-    normalizes the grid, then runs it one-shot (the legacy exact path)
-    or through `repro.core.executor`. Returns (SweepResult | None,
+def _sweep_impl(*args, **kwargs):
+    """Shared implementation behind `sweep` / `sweep_resumable`, traced
+    as one ``sweep`` span (`repro.obs.trace`); `_sweep_run` does the
+    work."""
+    with obs_trace.get_tracer().span("sweep"):
+        return _sweep_run(*args, **kwargs)
+
+
+def _sweep_run(profiles: Union[str, PlantProfile,
+                               Sequence[Union[str, PlantProfile]]],
+               epsilons: Sequence[float],
+               seeds: Sequence[int],
+               total_work: float,
+               max_time: float = 3600.0,
+               dt: float = 1.0,
+               tau_obj: float = 10.0,
+               adaptive: Union[None, RLSConfig,
+                               Sequence[RLSConfig]] = None,
+               policies: Union[None, pol.Policy,
+                               Sequence[pol.Policy]] = None,
+               collect_traces: bool = True,
+               summary_warmup: int = 0,
+               workloads: Union[None, PhaseSchedule,
+                                Sequence[PhaseSchedule]] = None,
+               detector: Union[None, DetectorConfig,
+                               Sequence[DetectorConfig]] = None,
+               faults: Union[None, flt.FaultSchedule,
+                             Sequence[flt.FaultSchedule]] = None,
+               guard: Union[None, bool, flt.GuardConfig] = None,
+               record_events: Union[None, bool, int] = None,
+               backend: str = "auto",
+               chunk_size: Optional[int] = None,
+               devices=None,
+               typed_pi: bool = False,
+               consume=None,
+               state=None,
+               stop_after: Optional[int] = None,
+               durable=None,
+               campaign=None):
+    """`_sweep_impl`'s work: normalizes the grid (``sweep/grid``, with
+    ``sweep/keys`` and ``sweep/rows``), then runs it one-shot (the
+    legacy exact path) or through `repro.core.executor`, and assembles
+    the result (``sweep/summary``). Returns (SweepResult | None,
     ExecState | None)."""
-    single = isinstance(profiles, (str, PlantProfile))
-    profs = [_resolve(p) for p in ([profiles] if single else profiles)]
-    eps = [float(e) for e in epsilons]
-    seeds = [int(s) for s in seeds]
-    if not (profs and eps and seeds):
-        raise ValueError("sweep needs at least one profile, epsilon and "
-                         "seed")
-    if adaptive is not None and policies is not None:
-        raise ValueError("pass policies= or adaptive=, not both "
-                         "(adaptive= is sugar for PIPolicy(adaptive=...))")
-    if policies is None:
-        if adaptive is None:
-            pls, squeeze_pol = [PIPolicy()], True
+    tracer = obs_trace.get_tracer()
+    with tracer.span("sweep/grid"):
+        single = isinstance(profiles, (str, PlantProfile))
+        profs = [_resolve(p) for p in ([profiles] if single else profiles)]
+        eps = [float(e) for e in epsilons]
+        seeds = [int(s) for s in seeds]
+        if not (profs and eps and seeds):
+            raise ValueError("sweep needs at least one profile, epsilon and "
+                             "seed")
+        if adaptive is not None and policies is not None:
+            raise ValueError("pass policies= or adaptive=, not both "
+                             "(adaptive= is sugar for PIPolicy(adaptive=...))")
+        if policies is None:
+            if adaptive is None:
+                pls, squeeze_pol = [PIPolicy()], True
+            else:
+                single_cfg = isinstance(adaptive, RLSConfig)
+                cfgs = [adaptive] if single_cfg else list(adaptive)
+                if not cfgs:
+                    raise ValueError("adaptive= needs at least one RLSConfig")
+                pls = [PIPolicy(adaptive=c) for c in cfgs]
+                squeeze_pol = single_cfg
         else:
-            single_cfg = isinstance(adaptive, RLSConfig)
-            cfgs = [adaptive] if single_cfg else list(adaptive)
-            if not cfgs:
-                raise ValueError("adaptive= needs at least one RLSConfig")
-            pls = [PIPolicy(adaptive=c) for c in cfgs]
-            squeeze_pol = single_cfg
-    else:
-        squeeze_pol = isinstance(policies, pol.Policy)
-        pls = [policies] if squeeze_pol else list(policies)
-        if not pls:
-            raise ValueError("policies= needs at least one Policy")
-    branches, kinds = pol.resolve_kinds(pls)
-    pv = jnp.stack([profile_values(p) for p in profs])
-    gv = jnp.stack([
-        jnp.stack([gains_values(PIGains.from_model(p, e, tau_obj))
-                   for e in eps]) for p in profs])
-    keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-    # policy values grid (P, A, PARAM_DIM), built at the eps[0] design
-    # point per profile (cf. the adaptive grid: kl_ref/tau_obj depend
-    # only on the profile)
-    av = jnp.stack([
-        jnp.stack([pol.policy_values(
-            p_, p, PIGains.from_model(p, eps[0], tau_obj), kind=k)
-            for p_, k in zip(pls, kinds)]) for p in profs])
-    if workloads is None:
-        sv, squeeze_w = None, None
-    else:
-        squeeze_w = isinstance(workloads, PhaseSchedule)
-        wls = [workloads] if squeeze_w else list(workloads)
-        if not wls:
-            raise ValueError("workloads= needs at least one "
-                             "PhaseSchedule")
-        # schedule leaves stacked (P, W, ...): resolved per profile, all
-        # packed to the grid's common row count (piecewise chaining
-        # keeps long scripts in whole 16-row pieces)
-        rows = max(chain_rows(len(w.phases)) for w in wls)
-        sv = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs),
-            *[jax.tree_util.tree_map(lambda *ws: jnp.stack(ws),
-                                     *[w.resolve(p, rows) for w in wls])
-              for p in profs])
-    det_grid = (detector is not None
-                and not isinstance(detector, DetectorConfig))
-    if detector is None:
-        dv = None
-    elif det_grid:
-        det_cfgs = list(detector)
-        if not det_cfgs:
-            raise ValueError("detector= needs at least one "
-                             "DetectorConfig")
-        # detector hyperparameter grid (P, D, DET_PARAM_DIM): a new D
-        # axis between [workloads] and seeds, like the adaptive= grid
-        dv = jnp.stack([jnp.stack([detector_values(d, p)
-                                   for d in det_cfgs]) for p in profs])
-    else:
-        dv = jnp.stack([detector_values(detector, p) for p in profs])
-    fault_grid = (faults is not None
-                  and not isinstance(faults, flt.FaultSchedule))
-    if faults is None:
-        fv = None
-    elif fault_grid:
-        fault_scheds = list(faults)
-        if not fault_scheds:
-            raise ValueError("faults= needs at least one FaultSchedule")
-        # fault-scenario axis (F, MAX_FAULT_ROWS): plant-independent
-        # leaves stacked across schedules, the innermost grid axis
-        # before seeds
-        fv = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs),
-            *[f.resolve() for f in fault_scheds])
-    else:
-        fv = faults.resolve()  # single schedule: no axis, like detector
-    gvl = (None if not guard
-           else flt.guard_values(None if guard is True else guard))
-    if typed_pi and branches != ("pi",):
-        raise ValueError("typed_pi= is the single-branch fixed-gain PI "
-                         f"fast path; this grid dispatches {branches}")
-    if typed_pi and (fv is not None or gvl is not None):
-        raise ValueError("typed_pi= is the guard-free fixed-gain PI "
-                         "fast path; faults=/guard= need the packed "
-                         "engine")
-    n_events = _resolve_n_events(record_events)
-    if typed_pi and n_events:
-        raise ValueError("typed_pi= is the recorder-free fixed-gain PI "
-                         "fast path; record_events= needs the packed "
-                         "engine")
-    if backend not in ("scan", "pallas", "auto"):
-        raise ValueError(f"unknown backend {backend!r}; choose "
-                         "'scan', 'pallas' or 'auto'")
-    # capability dispatch: the mega-kernel carry has no recorder ring
-    # (documented fallback — recorded grids ride the scan engine)
-    pallas_ok = (branches == ("pi",) and sv is None and dv is None
-                 and fv is None and gvl is None and n_events == 0)
-    if backend == "auto":
-        # capability dispatch: the mega-kernel covers the flagship
-        # fixed-gain PI path and pays off where it lowers natively; the
-        # interpreted kernel is for correctness work, not speed.
-        # typed_pi= names a carry of the scan engine, so it keeps the scan
-        backend = ("pallas" if pallas_ok and not typed_pi
-                   and jax.default_backend() == "tpu" else "scan")
-    elif backend == "pallas" and typed_pi:
-        raise ValueError("typed_pi= is a carry of the scan engine; the "
-                         "Pallas kernel has its own — use backend='scan'")
-    elif backend == "pallas" and not pallas_ok:
-        raise ValueError(
-            "backend='pallas' covers the fixed-gain PI path only "
-            "(static plant, no detector, no faults/guard, no flight "
-            "recorder); this grid "
-            f"needs branches={branches}, workloads={sv is not None}, "
-            f"detector={dv is not None}, faults={fv is not None}, "
-            f"guard={gvl is not None}, record_events={n_events > 0} — "
-            "use backend='scan'")
-    max_steps = _bucket_steps(int(np.ceil(max_time / dt)))
-    use_exec = (backend != "scan" or chunk_size is not None
-                or devices is not None or consume is not None
-                or state is not None or stop_after is not None
-                or durable is not None)
+            squeeze_pol = isinstance(policies, pol.Policy)
+            pls = [policies] if squeeze_pol else list(policies)
+            if not pls:
+                raise ValueError("policies= needs at least one Policy")
+        branches, kinds = pol.resolve_kinds(pls)
+        pv = jnp.stack([profile_values(p) for p in profs])
+        gv = jnp.stack([
+            jnp.stack([gains_values(PIGains.from_model(p, e, tau_obj))
+                       for e in eps]) for p in profs])
+        with tracer.span("sweep/keys"):
+            keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+        # policy values grid (P, A, PARAM_DIM), built at the eps[0] design
+        # point per profile (cf. the adaptive grid: kl_ref/tau_obj depend
+        # only on the profile)
+        av = jnp.stack([
+            jnp.stack([pol.policy_values(
+                p_, p, PIGains.from_model(p, eps[0], tau_obj), kind=k)
+                for p_, k in zip(pls, kinds)]) for p in profs])
+        if workloads is None:
+            sv, squeeze_w = None, None
+        else:
+            squeeze_w = isinstance(workloads, PhaseSchedule)
+            wls = [workloads] if squeeze_w else list(workloads)
+            if not wls:
+                raise ValueError("workloads= needs at least one "
+                                 "PhaseSchedule")
+            # schedule leaves stacked (P, W, ...): resolved per profile, all
+            # packed to the grid's common row count (piecewise chaining
+            # keeps long scripts in whole 16-row pieces)
+            rows = max(chain_rows(len(w.phases)) for w in wls)
+            sv = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs),
+                *[jax.tree_util.tree_map(lambda *ws: jnp.stack(ws),
+                                         *[w.resolve(p, rows) for w in wls])
+                  for p in profs])
+        det_grid = (detector is not None
+                    and not isinstance(detector, DetectorConfig))
+        if detector is None:
+            dv = None
+        elif det_grid:
+            det_cfgs = list(detector)
+            if not det_cfgs:
+                raise ValueError("detector= needs at least one "
+                                 "DetectorConfig")
+            # detector hyperparameter grid (P, D, DET_PARAM_DIM): a new D
+            # axis between [workloads] and seeds, like the adaptive= grid
+            dv = jnp.stack([jnp.stack([detector_values(d, p)
+                                       for d in det_cfgs]) for p in profs])
+        else:
+            dv = jnp.stack([detector_values(detector, p) for p in profs])
+        fault_grid = (faults is not None
+                      and not isinstance(faults, flt.FaultSchedule))
+        if faults is None:
+            fv = None
+        elif fault_grid:
+            fault_scheds = list(faults)
+            if not fault_scheds:
+                raise ValueError("faults= needs at least one FaultSchedule")
+            # fault-scenario axis (F, MAX_FAULT_ROWS): plant-independent
+            # leaves stacked across schedules, the innermost grid axis
+            # before seeds
+            fv = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs),
+                *[f.resolve() for f in fault_scheds])
+        else:
+            fv = faults.resolve()  # single schedule: no axis, like detector
+        gvl = (None if not guard
+               else flt.guard_values(None if guard is True else guard))
+        if typed_pi and branches != ("pi",):
+            raise ValueError("typed_pi= is the single-branch fixed-gain PI "
+                             f"fast path; this grid dispatches {branches}")
+        if typed_pi and (fv is not None or gvl is not None):
+            raise ValueError("typed_pi= is the guard-free fixed-gain PI "
+                             "fast path; faults=/guard= need the packed "
+                             "engine")
+        n_events = _resolve_n_events(record_events)
+        if typed_pi and n_events:
+            raise ValueError("typed_pi= is the recorder-free fixed-gain PI "
+                             "fast path; record_events= needs the packed "
+                             "engine")
+        if backend not in ("scan", "pallas", "auto"):
+            raise ValueError(f"unknown backend {backend!r}; choose "
+                             "'scan', 'pallas' or 'auto'")
+        # capability dispatch: the mega-kernel carry has no recorder ring
+        # (documented fallback — recorded grids ride the scan engine)
+        pallas_ok = (branches == ("pi",) and sv is None and dv is None
+                     and fv is None and gvl is None and n_events == 0)
+        if backend == "auto":
+            # capability dispatch: the mega-kernel covers the flagship
+            # fixed-gain PI path and pays off where it lowers natively; the
+            # interpreted kernel is for correctness work, not speed.
+            # typed_pi= names a carry of the scan engine, so it keeps the scan
+            backend = ("pallas" if pallas_ok and not typed_pi
+                       and jax.default_backend() == "tpu" else "scan")
+        elif backend == "pallas" and typed_pi:
+            raise ValueError("typed_pi= is a carry of the scan engine; the "
+                             "Pallas kernel has its own — use backend='scan'")
+        elif backend == "pallas" and not pallas_ok:
+            raise ValueError(
+                "backend='pallas' covers the fixed-gain PI path only "
+                "(static plant, no detector, no faults/guard, no flight "
+                "recorder); this grid "
+                f"needs branches={branches}, workloads={sv is not None}, "
+                f"detector={dv is not None}, faults={fv is not None}, "
+                f"guard={gvl is not None}, record_events={n_events > 0} — "
+                "use backend='scan'")
+        max_steps = _bucket_steps(int(np.ceil(max_time / dt)))
+        use_exec = (backend != "scan" or chunk_size is not None
+                    or devices is not None or consume is not None
+                    or state is not None or stop_after is not None
+                    or durable is not None)
+        if use_exec:
+            P, E, A, S = len(profs), len(eps), len(pls), len(seeds)
+            W = (1 if sv is None
+                 else jax.tree_util.tree_leaves(sv)[0].shape[1])
+            D = dv.shape[1] if det_grid else 1
+            F = (jax.tree_util.tree_leaves(fv)[0].shape[0] if fault_grid
+                 else 1)
+            shape7 = (P, E, A, W, D, F, S)
+            n_runs = int(np.prod(shape7))
+            with tracer.span("sweep/rows"):
+                # flatten the grid to per-run rows (grid-nest order, so the
+                # merged leading axis reshapes straight back to
+                # (P,E,A,[W],[D],[F],S))
+                (ip, ie, ia, iw, idet, ifl,
+                 is_) = np.indices(shape7).reshape(7, n_runs)
+                batched = {"prof": np.asarray(pv)[ip],
+                           "gains": np.asarray(gv)[ip, ie],
+                           "pvals": np.asarray(av)[ip, ia],
+                           "key": np.asarray(keys)[is_]}
+                if sv is not None:
+                    batched["sched"] = jax.tree_util.tree_map(
+                        lambda x: np.asarray(x)[ip, iw], sv)
+                if dv is not None:
+                    batched["det"] = (np.asarray(dv)[ip, idet] if det_grid
+                                      else np.asarray(dv)[ip])
+                if fv is not None:
+                    # fault rows always ride the per-run rows here (a single
+                    # schedule broadcasts), so chunk slicing stays uniform
+                    batched["faults"] = jax.tree_util.tree_map(
+                        lambda x: (np.asarray(x)[ifl] if fault_grid
+                                   else np.broadcast_to(
+                                       np.asarray(x),
+                                       (n_runs,) + np.shape(x)).copy()), fv)
+            if backend == "pallas":
+                # the op jits itself; with devices= the executor places one
+                # slice of every chunk on each device
+                fn = _flat_core_pallas(collect_traces)
+                shared = (float(total_work), float(max_time), float(dt),
+                          float(summary_warmup))
+                wrap = "none"
+            else:
+                fn = _flat_core(max_steps, branches, collect_traces,
+                                sv is not None, dv is not None, typed_pi,
+                                gvl is not None, n_events)
+                shared = (jnp.float32(total_work), jnp.float32(max_time),
+                          jnp.float32(dt), jnp.float32(summary_warmup))
+                if gvl is not None:
+                    shared = shared + (gvl,)
+                wrap = "jit"
     exec_state = None
     if not use_exec:
         traces, final = _jit_sweep(max_steps, branches, collect_traces,
@@ -1322,53 +1384,6 @@ def _sweep_impl(profiles: Union[str, PlantProfile,
             jnp.float32(summary_warmup), keys)
     else:
         from repro.core import executor
-        P, E, A, S = len(profs), len(eps), len(pls), len(seeds)
-        W = (1 if sv is None
-             else jax.tree_util.tree_leaves(sv)[0].shape[1])
-        D = dv.shape[1] if det_grid else 1
-        F = (jax.tree_util.tree_leaves(fv)[0].shape[0] if fault_grid
-             else 1)
-        shape7 = (P, E, A, W, D, F, S)
-        n_runs = int(np.prod(shape7))
-        # flatten the grid to per-run rows (grid-nest order, so the
-        # merged leading axis reshapes straight back to
-        # (P,E,A,[W],[D],[F],S))
-        (ip, ie, ia, iw, idet, ifl,
-         is_) = np.indices(shape7).reshape(7, n_runs)
-        batched = {"prof": np.asarray(pv)[ip],
-                   "gains": np.asarray(gv)[ip, ie],
-                   "pvals": np.asarray(av)[ip, ia],
-                   "key": np.asarray(keys)[is_]}
-        if sv is not None:
-            batched["sched"] = jax.tree_util.tree_map(
-                lambda x: np.asarray(x)[ip, iw], sv)
-        if dv is not None:
-            batched["det"] = (np.asarray(dv)[ip, idet] if det_grid
-                              else np.asarray(dv)[ip])
-        if fv is not None:
-            # fault rows always ride the per-run rows here (a single
-            # schedule broadcasts), so chunk slicing stays uniform
-            batched["faults"] = jax.tree_util.tree_map(
-                lambda x: (np.asarray(x)[ifl] if fault_grid
-                           else np.broadcast_to(
-                               np.asarray(x),
-                               (n_runs,) + np.shape(x)).copy()), fv)
-        if backend == "pallas":
-            # the op jits itself; with devices= the executor places one
-            # slice of every chunk on each device
-            fn = _flat_core_pallas(collect_traces)
-            shared = (float(total_work), float(max_time), float(dt),
-                      float(summary_warmup))
-            wrap = "none"
-        else:
-            fn = _flat_core(max_steps, branches, collect_traces,
-                            sv is not None, dv is not None, typed_pi,
-                            gvl is not None, n_events)
-            shared = (jnp.float32(total_work), jnp.float32(max_time),
-                      jnp.float32(dt), jnp.float32(summary_warmup))
-            if gvl is not None:
-                shared = shared + (gvl,)
-            wrap = "jit"
         if durable is not None:
             # journaled, retried, quarantine-capable campaign path —
             # same grid, same per-run rows, so the merged result is
@@ -1394,47 +1409,49 @@ def _sweep_impl(profiles: Union[str, PlantProfile,
         if merged is None:  # consume hook ran, or stop_after cut short
             return None, exec_state
         traces, final = merged
-        if backend == "pallas":
-            final = _carry_from_kernel_final(final)
-        out_shape = ((P, E, A) + ((W,) if sv is not None else ())
-                     + ((D,) if det_grid else ())
-                     + ((F,) if fault_grid else ()) + (S,))
-        reshape = lambda x: x.reshape(out_shape + x.shape[1:])
-        traces = (None if traces is None
-                  else jax.tree_util.tree_map(reshape, traces))
-        final = jax.tree_util.tree_map(reshape, final)
-    edges = {k: np.stack([_hist_edges(p)[k] for p in profs])
-             for k in ("progress_edges", "pcap_edges")}
-    summary = _summary_dict(final, edges)
+    with tracer.span("sweep/summary"):
+        if use_exec:
+            if backend == "pallas":
+                final = _carry_from_kernel_final(final)
+            out_shape = ((P, E, A) + ((W,) if sv is not None else ())
+                         + ((D,) if det_grid else ())
+                         + ((F,) if fault_grid else ()) + (S,))
+            reshape = lambda x: x.reshape(out_shape + x.shape[1:])
+            traces = (None if traces is None
+                      else jax.tree_util.tree_map(reshape, traces))
+            final = jax.tree_util.tree_map(reshape, final)
+        edges = {k: np.stack([_hist_edges(p)[k] for p in profs])
+                 for k in ("progress_edges", "pcap_edges")}
+        summary = _summary_dict(final, edges)
 
-    def squeeze(tree, axis):
-        return jax.tree_util.tree_map(
-            lambda x: x[(slice(None),) * axis + (0,)]
-            if hasattr(x, "ndim") and x.ndim > axis else x, tree)
+        def squeeze(tree, axis):
+            return jax.tree_util.tree_map(
+                lambda x: x[(slice(None),) * axis + (0,)]
+                if hasattr(x, "ndim") and x.ndim > axis else x, tree)
 
-    if squeeze_w:  # single PhaseSchedule: drop the W axis (P, E, A, W, S)
-        traces, final = squeeze(traces, 3), squeeze(final, 3)
-        summary = {k: v if k.endswith("_edges") else squeeze(v, 3)
-                   for k, v in summary.items()}
-    if squeeze_pol:
-        traces, final = squeeze(traces, 2), squeeze(final, 2)
-        summary = {k: v if k.endswith("_edges") else squeeze(v, 2)
-                   for k, v in summary.items()}
-    if single:
-        traces, final = squeeze(traces, 0), squeeze(final, 0)
-        summary = squeeze(summary, 0)
-    return SweepResult(traces=traces,
-                       exec_time=final.t,
-                       energy=final.plant.energy,
-                       work=final.plant.work,
-                       completed=final.plant.work >= total_work,
-                       n_steps=final.steps,
-                       summary=summary,
-                       detections=(None if final.det is None
-                                   else final.det[..., DET_N_DETECT]),
-                       guard_state=final.guard,
-                       events=final.events
-                       ), exec_state
+        if squeeze_w:  # single PhaseSchedule: drop the W axis (P, E, A, W, S)
+            traces, final = squeeze(traces, 3), squeeze(final, 3)
+            summary = {k: v if k.endswith("_edges") else squeeze(v, 3)
+                       for k, v in summary.items()}
+        if squeeze_pol:
+            traces, final = squeeze(traces, 2), squeeze(final, 2)
+            summary = {k: v if k.endswith("_edges") else squeeze(v, 2)
+                       for k, v in summary.items()}
+        if single:
+            traces, final = squeeze(traces, 0), squeeze(final, 0)
+            summary = squeeze(summary, 0)
+        return SweepResult(traces=traces,
+                           exec_time=final.t,
+                           energy=final.plant.energy,
+                           work=final.plant.work,
+                           completed=final.plant.work >= total_work,
+                           n_steps=final.steps,
+                           summary=summary,
+                           detections=(None if final.det is None
+                                       else final.det[..., DET_N_DETECT]),
+                           guard_state=final.guard,
+                           events=final.events
+                           ), exec_state
 
 
 def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,

@@ -1,17 +1,15 @@
-"""Shared retry/backoff ladder: one policy object, two consumers.
+"""Retry/backoff ladder of the campaign supervisor.
 
-The campaign supervisor (`repro.core.supervisor`) and the push-gateway
-sink (`repro.obs.sink.PushSink`) both face the same problem — a flaky
-downstream (an XLA chunk, an HTTP collector) whose transient failures
-should be absorbed with exponential backoff + jitter under a bounded
-retry budget, never by spinning or by giving up on the first hiccup.
+The campaign supervisor (`repro.core.supervisor`) faces a flaky
+downstream (an XLA chunk) whose transient failures should be absorbed
+with exponential backoff + jitter under a bounded retry budget, never
+by spinning or by giving up on the first hiccup.
 `RetryPolicy` is that ladder as a frozen, picklable value (it rides the
 supervisor's campaign spec through pickle); `call_with_retries` is the
 simple synchronous driver for callers without their own orchestration
 loop.
 
-Stdlib only — importing this module can never perturb jax tracing, and
-the sink layer keeps its no-jax guarantee.
+Stdlib only — importing this module can never perturb jax tracing.
 """
 from __future__ import annotations
 

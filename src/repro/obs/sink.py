@@ -16,12 +16,6 @@ is the push side:
     already expose: per-chunk summary rows (or full per-run rows) go to
     disk and the chunk arrays are dropped, keeping campaign memory
     O(chunk).
-  * `PushSink` — HTTP push-gateway client: rows spool in a bounded
-    in-memory deque (oldest dropped and counted when full) and flush as
-    newline-delimited JSON batches through the shared
-    `repro.obs.retry` ladder. Push failures are swallowed and counted
-    (``errors`` + ``sink_errors_total``) — telemetry export must never
-    take a campaign down.
   * ``EventLog(sink=...)`` (in `repro.obs.events`) streams every decoded
     decision-stream event through the same writer before eviction.
 
@@ -30,19 +24,15 @@ perturb jax tracing.
 """
 from __future__ import annotations
 
-import collections
 import json
 import os
 import threading
-import urllib.error
-import urllib.request
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
 from repro.obs import metrics as obs_metrics
-from repro.obs.retry import RetryPolicy, call_with_retries
 
 
 class JsonlSink:
@@ -227,119 +217,6 @@ class MetricsSampler:
 
     def __exit__(self, *exc) -> None:
         self.stop()
-
-
-# ------------------------------------------------------ HTTP push sink
-def _http_post(url: str, data: bytes, timeout_s: float) -> None:
-    req = urllib.request.Request(
-        url, data=data,
-        headers={"Content-Type": "application/x-ndjson"})
-    with urllib.request.urlopen(req, timeout=timeout_s) as resp:
-        status = getattr(resp, "status", 200)
-        if status >= 300:
-            raise urllib.error.HTTPError(url, status, "push rejected",
-                                         resp.headers, None)
-
-
-class PushSink:
-    """Push-gateway sink: bounded spool -> batched HTTP POST with the
-    shared retry ladder.
-
-    ``write(obj)`` only appends to an in-memory deque capped at
-    ``max_spool`` rows (oldest dropped and counted in ``dropped`` —
-    bounded memory beats complete telemetry). ``flush()`` drains the
-    spool in ``batch``-row newline-delimited JSON posts; each post runs
-    through `call_with_retries` with ``policy``, and a batch that still
-    fails after the retry budget is re-spooled at the FRONT (so the next
-    flush retries it first) with ``errors`` and the registry counter
-    ``sink_errors_total{sink="push"}`` incremented — the caller never
-    sees the exception. Pass ``post=`` to substitute the transport
-    (tests use a local stdlib HTTP server or a plain callable).
-    """
-
-    def __init__(self, url: str, *, max_spool: int = 4096,
-                 batch: int = 256, timeout_s: float = 5.0,
-                 policy: Optional[RetryPolicy] = None,
-                 post: Optional[Callable[[str, bytes, float], None]] = None,
-                 registry: Optional[obs_metrics.MetricsRegistry] = None,
-                 sleep: Callable[[float], None] = None):
-        if max_spool < 1 or batch < 1:
-            raise ValueError("max_spool and batch must be >= 1")
-        self.url = url
-        self.batch = int(batch)
-        self.timeout_s = float(timeout_s)
-        self.policy = policy or RetryPolicy(max_retries=3, base_s=0.05)
-        self.pushed = 0
-        self.posts = 0
-        self.errors = 0
-        self.dropped = 0
-        self._post = post or _http_post
-        self._sleep = sleep if sleep is not None else None
-        self._spool: collections.deque = collections.deque(
-            maxlen=int(max_spool))
-        self._lock = threading.Lock()
-        reg = registry or obs_metrics.get_registry()
-        self._c_err = reg.counter(
-            "sink_errors_total",
-            "Telemetry push batches abandoned after the retry budget",
-            labelnames=("sink",))
-        self._c_drop = reg.counter(
-            "sink_dropped_rows_total",
-            "Telemetry rows evicted from a full push spool",
-            labelnames=("sink",))
-
-    def write(self, obj: Any) -> None:
-        line = json.dumps(obj, separators=(",", ":"), default=_jsonable)
-        with self._lock:
-            if len(self._spool) == self._spool.maxlen:
-                self.dropped += 1
-                self._c_drop.inc(sink="push")
-            self._spool.append(line)
-
-    def write_many(self, objs: Sequence[Any]) -> None:
-        for o in objs:
-            self.write(o)
-
-    def __len__(self) -> int:
-        with self._lock:
-            return len(self._spool)
-
-    def flush(self) -> None:
-        while True:
-            with self._lock:
-                if not self._spool:
-                    return
-                rows = [self._spool.popleft()
-                        for _ in range(min(self.batch, len(self._spool)))]
-            payload = ("\n".join(rows) + "\n").encode("utf-8")
-
-            def _do():
-                self.posts += 1
-                self._post(self.url, payload, self.timeout_s)
-
-            kw = {} if self._sleep is None else {"sleep": self._sleep}
-            try:
-                call_with_retries(_do, self.policy, **kw)
-                self.pushed += len(rows)
-            except Exception:
-                # swallowed by design: telemetry must never take the
-                # campaign down. Re-spool at the front so the rows get
-                # another chance on the next flush (the deque cap still
-                # bounds memory if the gateway stays dark).
-                self.errors += 1
-                self._c_err.inc(sink="push")
-                with self._lock:
-                    self._spool.extendleft(reversed(rows))
-                return
-
-    def close(self) -> None:
-        self.flush()
-
-    def __enter__(self) -> "PushSink":
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.close()
 
 
 # ------------------------------------------------------- consume= hooks

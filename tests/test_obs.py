@@ -329,6 +329,7 @@ def test_tracer_disabled_is_noop_enabled_records_spans(tmp_path):
     assert spans[0]["name"] == "executor/compute"
     assert spans[0]["dur"] >= 0
     assert spans[0]["args"]["chunk"] == 1
+    tr.enabled = False
     path = tmp_path / "t.json"
     tr.write(path)
     obs_trace.validate_chrome_trace(json.loads(path.read_text()))
@@ -337,6 +338,190 @@ def test_tracer_disabled_is_noop_enabled_records_spans(tmp_path):
             {"traceEvents": []}, require_spans=True)
     with pytest.raises(ValueError):
         obs_trace.validate_chrome_trace({"nope": 1})
+
+
+@pytest.fixture
+def tracer():
+    """The process tracer, enabled and empty; disabled again after."""
+    tr = obs_trace.enable(True)
+    tr.clear()
+    try:
+        yield tr
+    finally:
+        obs_trace.enable(False)
+        tr.clear()
+
+
+def _spans(tr):
+    return [e for e in tr.events() if e["ph"] == "X"
+            and e["name"] != obs_trace.GC_SPAN]
+
+
+def test_tracer_records_parent_and_call_for_nested_spans():
+    tr = obs_trace.Tracer(enabled=True)
+    try:
+        for _ in range(2):
+            with tr.span("sweep"):
+                with tr.span("sweep/grid"):
+                    with tr.span("sweep/keys"):
+                        pass
+                with tr.span("executor/compute", chunk=0):
+                    pass
+    finally:
+        tr.enabled = False
+    got = [(e["name"], e["args"]["parent"], e["args"]["call"])
+           for e in _spans(tr)]
+    first, second = got[:4], got[4:]
+    assert [g[:2] for g in first] == [
+        ("sweep/keys", "sweep/grid"), ("sweep/grid", "sweep"),
+        ("executor/compute", "sweep"), ("sweep", None)]
+    assert len({g[2] for g in first}) == 1
+    assert len({g[2] for g in second}) == 1
+    assert first[0][2] != second[0][2]
+    assert _spans(tr)[2]["args"]["chunk"] == 0
+
+
+def test_disabled_tracer_builds_no_annotation_and_leaves_gc_alone(
+        monkeypatch):
+    def refuse(*a, **kw):
+        raise AssertionError("a disabled tracer built an annotation")
+
+    import gc
+    hooks = list(gc.callbacks)
+    tr = obs_trace.Tracer()
+    monkeypatch.setattr(tr, "_annotation", refuse)
+    with tr.span("sweep"):
+        gc.collect()
+    tr.instant("marker")
+    assert tr.events() == []
+    assert gc.callbacks == hooks
+
+
+def test_enabled_tracer_records_gc_and_unhooks_when_disabled():
+    import gc
+    tr = obs_trace.Tracer(enabled=True)
+    assert tr._gc_hook in gc.callbacks
+    try:
+        with tr.span("plane/tick"):
+            gc.collect()
+    finally:
+        tr.enabled = False
+    assert tr._gc_hook not in gc.callbacks
+    tick = [e for e in tr.events() if e["name"] == "plane/tick"][0]
+    gcs = [e for e in tr.events() if e["name"] == obs_trace.GC_SPAN
+           and e["args"]["generation"] == 2]
+    assert gcs and gcs[0]["args"]["parent"] == "plane/tick"
+    assert gcs[0]["args"]["call"] == tick["args"]["call"]
+    assert tick["ts"] <= gcs[0]["ts"] and \
+        gcs[0]["ts"] + gcs[0]["dur"] <= tick["ts"] + tick["dur"]
+    n = len(tr.events())
+    gc.collect()
+    assert len(tr.events()) == n
+
+
+def test_tracer_epoch_is_the_perf_counter_origin_of_ts():
+    import time
+    tr = obs_trace.Tracer(enabled=True)
+    try:
+        tr.clear()
+        t0 = time.perf_counter()
+        with tr.span("sweep"):
+            pass
+        t1 = time.perf_counter()
+    finally:
+        tr.enabled = False
+    ev = _spans(tr)[0]
+    start = tr.epoch + ev["ts"] * 1e-6
+    assert t0 <= start <= start + ev["dur"] * 1e-6 <= t1
+
+
+def test_sweep_spans_nest_with_the_executor_s_in_one_call(tracer):
+    sweep("gros", [0.1, 0.2], [1, 2, 3], total_work=50.0, max_time=64.0,
+          collect_traces=False, chunk_size=4)
+    ev = _spans(tracer)
+    by = {}
+    for e in ev:
+        by.setdefault(e["name"], []).append(e)
+    parent = {n: es[0]["args"]["parent"] for n, es in by.items()}
+    assert parent == {"sweep": None, "sweep/grid": "sweep",
+                      "sweep/keys": "sweep/grid",
+                      "sweep/rows": "sweep/grid",
+                      "sweep/summary": "sweep",
+                      "executor/prepare": "sweep",
+                      "executor/compute": "sweep",
+                      "executor/transfer": "sweep",
+                      "executor/merge": "sweep"}
+    assert len(by["executor/compute"]) == 2  # 6 runs in chunks of 4
+    assert len({e["args"]["call"] for e in ev}) == 1
+    order = [e["name"] for e in sorted(ev, key=lambda e: e["ts"])]
+    assert order.index("sweep/grid") < order.index("executor/prepare") \
+        < order.index("sweep/summary")
+
+
+def test_plane_period_spans_nest_under_ingest_and_tick(tracer):
+    from repro.core.plane import ControlPlane
+    plane = ControlPlane(profile="gros", capacity=16, max_beats=8)
+    ids = plane.add_tenants(5)
+    plane.ingest(np.repeat(ids, 3), np.tile([0.1, 0.4, 0.7], 5))
+    plane.tick(now=1.0)
+    parent = {e["name"]: e["args"]["parent"] for e in _spans(tracer)}
+    assert parent == {"plane/ingest": None, "plane/tick": None,
+                      "plane/aggregate": "plane/tick",
+                      "signals/median": "plane/aggregate",
+                      "signals/shift": "plane/aggregate",
+                      "plane/pack": "plane/tick",
+                      "executor/prepare": "plane/tick",
+                      "executor/compute": "plane/tick",
+                      "executor/transfer": "plane/tick",
+                      "executor/merge": "plane/tick",
+                      "plane/events": "plane/tick",
+                      "plane/publish": "plane/tick"}
+    calls = {e["name"]: e["args"]["call"] for e in _spans(tracer)}
+    assert calls["plane/ingest"] != calls["plane/tick"] \
+        == calls["executor/compute"] == calls["signals/median"]
+
+
+@pytest.mark.parametrize("guarded", [False, True],
+                         ids=["unguarded", "guarded"])
+def test_the_plane_tick_module_is_named_plane_tick(monkeypatch, guarded):
+    import jax
+    from repro.core import executor
+    from repro.core.plane import ControlPlane, tick_fn
+
+    plane = ControlPlane(profile="gros", capacity=16,
+                         guard=flt.GuardConfig() if guarded else None)
+    plane.add_tenants(3)
+    seen = {}
+
+    def capture(fn, rows, shared, n, **kw):
+        seen.update(fn=fn, rows=rows, shared=shared)
+        raise RuntimeError("captured")
+
+    monkeypatch.setattr(executor, "run_grid", capture)
+    with pytest.raises(RuntimeError, match="captured"):
+        plane.tick()
+    assert seen["fn"] is tick_fn(plane._branches, guarded)
+    text = jax.jit(seen["fn"]).lower(seen["rows"],
+                                     *seen["shared"]).as_text()
+    assert "module @jit_plane_tick" in text
+
+
+def test_the_scan_chunk_module_is_named_sweep_scan(monkeypatch):
+    import jax
+    from repro.core import executor
+    seen = {}
+
+    def capture(fn, batched, shared, n, **kw):
+        seen.update(fn=fn, batched=batched, shared=shared)
+        raise RuntimeError("captured")
+
+    monkeypatch.setattr(executor, "run_grid", capture)
+    with pytest.raises(RuntimeError, match="captured"):
+        sweep("gros", [0.1], [1, 2], total_work=50.0, max_time=64.0,
+              collect_traces=False, chunk_size=2, backend="scan")
+    text = jax.jit(seen["fn"]).lower(seen["batched"],
+                                     *seen["shared"]).as_text()
+    assert "module @jit_sweep_scan" in text
 
 
 # ---------------------------------------------------------------------------

@@ -1,19 +1,17 @@
-"""Durable campaign supervisor (repro.core.supervisor) + the shared
-retry ladder and the HTTP push sink it rides with.
+"""Durable campaign supervisor (repro.core.supervisor) + its retry
+ladder.
 
 The load-bearing property, inherited from the executor contract: every
 run's parameters and RNG ride in its own row, so a campaign that was
 retried, timed out, quarantined, killed -9 and resumed produces results
 bit-for-bit identical to one uninterrupted `run_grid` call.
 """
-import http.server
 import json
 import os
 import pickle
 import signal
 import subprocess
 import sys
-import threading
 from pathlib import Path
 
 import numpy as np
@@ -490,85 +488,3 @@ def test_harvest_dataset_durable_spools_parts(tmp_path):
 def test_resume_campaign_requires_spec(tmp_path):
     with pytest.raises(FileNotFoundError, match="campaign spec"):
         supervisor.resume_campaign(tmp_path)
-
-
-# ------------------------------------------------------------ push sink
-class _GatewayHandler(http.server.BaseHTTPRequestHandler):
-    fail_first = 2
-    posts = []
-    bodies = []
-
-    def do_POST(self):
-        cls = _GatewayHandler
-        n = int(self.headers.get("Content-Length", 0))
-        body = self.rfile.read(n)
-        cls.posts.append(self.path)
-        if len(cls.posts) <= cls.fail_first:
-            self.send_response(503)
-            self.end_headers()
-            return
-        cls.bodies.append(body)
-        self.send_response(200)
-        self.end_headers()
-
-    def log_message(self, *a):  # quiet
-        pass
-
-
-@pytest.fixture
-def gateway():
-    _GatewayHandler.posts, _GatewayHandler.bodies = [], []
-    srv = http.server.HTTPServer(("127.0.0.1", 0), _GatewayHandler)
-    th = threading.Thread(target=srv.serve_forever, daemon=True)
-    th.start()
-    yield f"http://127.0.0.1:{srv.server_address[1]}/push"
-    srv.shutdown()
-    th.join(timeout=5)
-
-
-def test_push_sink_retries_through_failing_gateway(gateway):
-    """The acceptance harness: a stdlib HTTP handler fails the first N
-    posts; the retry ladder delivers every row anyway."""
-    from repro.obs.sink import PushSink
-    _GatewayHandler.fail_first = 2
-    sink = PushSink(gateway, batch=64,
-                    policy=RetryPolicy(max_retries=4, base_s=0.01),
-                    sleep=lambda s: None)
-    rows = [{"i": i, "v": float(i) * 0.5} for i in range(10)]
-    sink.write_many(rows)
-    assert len(sink) == 10  # nothing sent until flush
-    sink.flush()
-    assert len(sink) == 0 and sink.pushed == 10 and sink.errors == 0
-    assert len(_GatewayHandler.posts) == 3  # 2 failures + 1 success
-    got = [json.loads(ln) for ln in
-           _GatewayHandler.bodies[0].decode().splitlines()]
-    assert got == rows
-
-
-def test_push_sink_swallows_exhausted_errors_and_respools():
-    from repro.obs.sink import PushSink
-
-    def dead_post(url, data, timeout):
-        raise OSError("gateway down")
-
-    sink = PushSink("http://x/push", max_spool=8, batch=4,
-                    policy=RetryPolicy(max_retries=1, base_s=0.0),
-                    post=dead_post, sleep=lambda s: None)
-    for i in range(6):
-        sink.write({"i": i})
-    sink.flush()  # must not raise
-    assert sink.errors == 1 and sink.pushed == 0
-    assert len(sink) == 6  # batch re-spooled at the front, none lost
-
-
-def test_push_sink_bounded_spool_drops_oldest():
-    from repro.obs.sink import PushSink
-    seen = []
-    sink = PushSink("http://x/push", max_spool=4, batch=16,
-                    post=lambda u, d, t: seen.append(d))
-    for i in range(7):
-        sink.write({"i": i})
-    assert sink.dropped == 3
-    sink.flush()
-    got = [json.loads(ln) for ln in seen[0].decode().splitlines()]
-    assert [r["i"] for r in got] == [3, 4, 5, 6]
