@@ -395,8 +395,7 @@ def fleet_sweep(profile, fc: FleetConfig, steps: int,
                      jnp.int32(fc.reallocate_every),
                      jnp.float32(fc.straggler_boost),
                      jnp.float32(steps), jnp.float32(fc.dt))
-    keys = np.stack([np.asarray(jax.random.PRNGKey(int(s)))
-                     for s in seeds])
+    keys = sim.seed_keys(seeds)
     if durable is not None:
         from repro.core import supervisor
         merged, _report = supervisor.run_durable(

@@ -788,6 +788,25 @@ def _jit_open_loop(steps: int):
     return jax.jit(jax.vmap(run, in_axes=(None, None, None, 0)))
 
 
+def _keys_are_low_words() -> bool:
+    """True where ``jax.random.PRNGKey(s)`` is ``[0, s mod 2**32]``: the
+    default ``threefry2x32`` implementation with 64-bit types off."""
+    return (jax.config.jax_default_prng_impl == "threefry2x32"
+            and not jax.config.jax_enable_x64)
+
+
+def seed_keys(seeds: Sequence[int]) -> np.ndarray:
+    """The ``(S, 2)`` uint32 raw keys ``jax.random.PRNGKey(s)`` gives for
+    each seed, built on the host in one step where the JAX setting allows
+    (one eager device dispatch per seed otherwise). Ints outside int64
+    raise ``OverflowError`` either way."""
+    seeds = [int(s) for s in seeds]
+    if not _keys_are_low_words():
+        return np.stack([np.asarray(jax.random.PRNGKey(s)) for s in seeds])
+    low = np.asarray(seeds, np.int64).astype(np.uint32)
+    return np.stack([np.zeros_like(low), low], axis=1)
+
+
 def open_loop_runs(profile: Union[str, PlantProfile], steps: int,
                    seeds: Sequence[int], pcap: Optional[float] = None,
                    dt: float = 1.0) -> dict:
@@ -796,7 +815,7 @@ def open_loop_runs(profile: Union[str, PlantProfile], steps: int,
     across profiles."""
     profile = _resolve(profile)
     pcap = profile.pcap_max if pcap is None else pcap
-    keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+    keys = seed_keys(seeds)
     return _jit_open_loop(int(steps))(profile_values(profile),
                                       jnp.float32(pcap), jnp.float32(dt),
                                       keys)
@@ -1223,7 +1242,7 @@ def _sweep_run(profiles: Union[str, PlantProfile,
             jnp.stack([gains_values(PIGains.from_model(p, e, tau_obj))
                        for e in eps]) for p in profs])
         with tracer.span("sweep/keys"):
-            keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+            keys = seed_keys(seeds)
         # policy values grid (P, A, PARAM_DIM), built at the eps[0] design
         # point per profile (cf. the adaptive grid: kl_ref/tau_obj depend
         # only on the profile)
@@ -1342,7 +1361,7 @@ def _sweep_run(profiles: Union[str, PlantProfile,
                 batched = {"prof": np.asarray(pv)[ip],
                            "gains": np.asarray(gv)[ip, ie],
                            "pvals": np.asarray(av)[ip, ia],
-                           "key": np.asarray(keys)[is_]}
+                           "key": keys[is_]}
                 if sv is not None:
                     batched["sched"] = jax.tree_util.tree_map(
                         lambda x: np.asarray(x)[ip, iw], sv)

@@ -1,19 +1,22 @@
 """Scan engine (repro.core.sim): equivalence with the stateful NRM loop,
 the in-scan RLS estimator vs its numpy oracle, trace-free summary mode
-vs full-trace reductions, vmapped sweep shapes/correctness, and the
-Eq. 3 replay helper."""
+vs full-trace reductions, vmapped sweep shapes/correctness, the
+host-built seed keys vs PRNGKey, and the Eq. 3 replay helper."""
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import PowerControlConfig
+from repro.core import sim
 from repro.core.adaptive import RLSAdapter, RLSConfig
 from repro.core.controller import PIGains
 from repro.core.nrm import NRM
-from repro.core.plant import PROFILES, pcap_linearize
-from repro.core.sim import (hist_quantile, replay_model,
-                            simulate_closed_loop, sweep)
+from repro.core.plant import PROFILES, pcap_linearize, simulate
+from repro.core.sim import (hist_quantile, open_loop_runs, replay_model,
+                            seed_keys, simulate_closed_loop, sweep)
 
 
 @pytest.mark.parametrize("name", ["gros", "dahu"])
@@ -83,13 +86,60 @@ def test_sweep_shapes_and_tradeoff_direction():
     assert res1.exec_time.shape == (3, 2)
 
 
-def test_sweep_matches_single_runs():
-    """A sweep cell equals simulate_closed_loop at the same (eps, seed)."""
-    res = sweep("gros", [0.1], [7], total_work=1000.0)
-    one = simulate_closed_loop("gros", 0.1, total_work=1000.0, seed=7)
+@pytest.mark.parametrize("seed", [7, 2**31 + 4242, 2**32 + 5])
+def test_sweep_matches_single_runs(seed):
+    """A sweep cell equals simulate_closed_loop at the same (eps, seed),
+    also for seeds past 2**31 and 2**32 (the sweep builds its keys on the
+    host; the single run keys with PRNGKey(seed))."""
+    res = sweep("gros", [0.1], [seed], total_work=1000.0)
+    one = simulate_closed_loop("gros", 0.1, total_work=1000.0, seed=seed)
     assert float(res.exec_time[0, 0]) == pytest.approx(one.exec_time)
     assert float(res.energy[0, 0]) == pytest.approx(one.energy, rel=1e-5)
     assert int(res.n_steps[0, 0]) == one.n_steps
+
+
+KEY_SEEDS = [0, 1, 7, 2**31 - 1, 2**31, 3_100_000_001, 2**32 - 1, 2**32,
+             2**32 + 5, -1, -5, 2**40 + 3, 2**63 - 1, -2**63]
+
+
+def _loop_keys(seeds):
+    return np.stack([np.asarray(jax.random.PRNGKey(int(s))) for s in seeds])
+
+
+@pytest.mark.parametrize("seed", KEY_SEEDS)
+def test_seed_keys_equal_prngkey_bit_for_bit(seed):
+    got, want = seed_keys([seed]), _loop_keys([seed])
+    assert got.dtype == want.dtype == np.uint32
+    assert got.shape == want.shape == (1, 2)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("seed", [2**63, 2**64])
+def test_seed_keys_overflow_like_prngkey(seed):
+    with pytest.raises(OverflowError):
+        jax.random.PRNGKey(seed)
+    with pytest.raises(OverflowError):
+        seed_keys([0, seed])
+
+
+def test_seed_keys_fallback_is_the_prngkey_loop(monkeypatch):
+    """Under any other JAX setting the keys come from PRNGKey itself."""
+    monkeypatch.setattr(sim, "_keys_are_low_words", lambda: False)
+    got = seed_keys(KEY_SEEDS)
+    assert got.dtype == np.uint32 and got.shape == (len(KEY_SEEDS), 2)
+    np.testing.assert_array_equal(got, _loop_keys(KEY_SEEDS))
+
+
+def test_open_loop_runs_key_each_seed_with_prngkey():
+    prof = PROFILES["gros"]
+    seeds = [3, 2**32 + 3]
+    out = open_loop_runs(prof, 16, seeds)
+    for i, s in enumerate(seeds):
+        one = simulate(prof, jnp.full((16,), prof.pcap_max), 1.0,
+                       jax.random.PRNGKey(s))
+        for k in one:
+            np.testing.assert_array_equal(np.asarray(out[k])[i],
+                                          np.asarray(one[k]), err_msg=k)
 
 
 def test_early_exit_mask_freezes_state():
