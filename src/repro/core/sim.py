@@ -1205,10 +1205,11 @@ def _sweep_run(profiles: Union[str, PlantProfile,
                durable=None,
                campaign=None):
     """`_sweep_impl`'s work: normalizes the grid (``sweep/grid``, with
-    ``sweep/keys`` and ``sweep/rows``), then runs it one-shot (the
-    legacy exact path) or through `repro.core.executor`, and assembles
-    the result (``sweep/summary``). Returns (SweepResult | None,
-    ExecState | None)."""
+    ``sweep/keys``, ``sweep/scenario`` and ``sweep/rows``), then runs it
+    one-shot (the legacy exact path) or through `repro.core.executor`,
+    counts a scan-engine result's runs in ``closed_loop_runs_total
+    {path="scan"}``, and assembles the result (``sweep/summary``).
+    Returns (SweepResult | None, ExecState | None)."""
     tracer = obs_trace.get_tracer()
     with tracer.span("sweep/grid"):
         single = isinstance(profiles, (str, PlantProfile))
@@ -1250,56 +1251,62 @@ def _sweep_run(profiles: Union[str, PlantProfile,
             jnp.stack([pol.policy_values(
                 p_, p, PIGains.from_model(p, eps[0], tau_obj), kind=k)
                 for p_, k in zip(pls, kinds)]) for p in profs])
-        if workloads is None:
-            sv, squeeze_w = None, None
-        else:
-            squeeze_w = isinstance(workloads, PhaseSchedule)
-            wls = [workloads] if squeeze_w else list(workloads)
-            if not wls:
-                raise ValueError("workloads= needs at least one "
-                                 "PhaseSchedule")
-            # schedule leaves stacked (P, W, ...): resolved per profile, all
-            # packed to the grid's common row count (piecewise chaining
-            # keeps long scripts in whole 16-row pieces)
-            rows = max(chain_rows(len(w.phases)) for w in wls)
-            sv = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs),
-                *[jax.tree_util.tree_map(lambda *ws: jnp.stack(ws),
-                                         *[w.resolve(p, rows) for w in wls])
-                  for p in profs])
-        det_grid = (detector is not None
-                    and not isinstance(detector, DetectorConfig))
-        if detector is None:
-            dv = None
-        elif det_grid:
-            det_cfgs = list(detector)
-            if not det_cfgs:
-                raise ValueError("detector= needs at least one "
-                                 "DetectorConfig")
-            # detector hyperparameter grid (P, D, DET_PARAM_DIM): a new D
-            # axis between [workloads] and seeds, like the adaptive= grid
-            dv = jnp.stack([jnp.stack([detector_values(d, p)
-                                       for d in det_cfgs]) for p in profs])
-        else:
-            dv = jnp.stack([detector_values(detector, p) for p in profs])
-        fault_grid = (faults is not None
-                      and not isinstance(faults, flt.FaultSchedule))
-        if faults is None:
-            fv = None
-        elif fault_grid:
-            fault_scheds = list(faults)
-            if not fault_scheds:
-                raise ValueError("faults= needs at least one FaultSchedule")
-            # fault-scenario axis (F, MAX_FAULT_ROWS): plant-independent
-            # leaves stacked across schedules, the innermost grid axis
-            # before seeds
-            fv = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs),
-                *[f.resolve() for f in fault_scheds])
-        else:
-            fv = faults.resolve()  # single schedule: no axis, like detector
-        gvl = (None if not guard
-               else flt.guard_values(None if guard is True else guard))
+        with tracer.span("sweep/scenario"):
+            if workloads is None:
+                sv, squeeze_w = None, None
+            else:
+                squeeze_w = isinstance(workloads, PhaseSchedule)
+                wls = [workloads] if squeeze_w else list(workloads)
+                if not wls:
+                    raise ValueError("workloads= needs at least one "
+                                     "PhaseSchedule")
+                # schedule leaves stacked (P, W, ...): resolved per
+                # profile, all packed to the grid's common row count
+                # (piecewise chaining keeps long scripts in whole 16-row
+                # pieces)
+                rows = max(chain_rows(len(w.phases)) for w in wls)
+                sv = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs),
+                    *[jax.tree_util.tree_map(
+                        lambda *ws: jnp.stack(ws),
+                        *[w.resolve(p, rows) for w in wls])
+                      for p in profs])
+            det_grid = (detector is not None
+                        and not isinstance(detector, DetectorConfig))
+            if detector is None:
+                dv = None
+            elif det_grid:
+                det_cfgs = list(detector)
+                if not det_cfgs:
+                    raise ValueError("detector= needs at least one "
+                                     "DetectorConfig")
+                # detector hyperparameter grid (P, D, DET_PARAM_DIM): a new
+                # D axis between [workloads] and seeds, like the adaptive=
+                # grid
+                dv = jnp.stack([jnp.stack([detector_values(d, p)
+                                           for d in det_cfgs])
+                                for p in profs])
+            else:
+                dv = jnp.stack([detector_values(detector, p) for p in profs])
+            fault_grid = (faults is not None
+                          and not isinstance(faults, flt.FaultSchedule))
+            if faults is None:
+                fv = None
+            elif fault_grid:
+                fault_scheds = list(faults)
+                if not fault_scheds:
+                    raise ValueError("faults= needs at least one "
+                                     "FaultSchedule")
+                # fault-scenario axis (F, MAX_FAULT_ROWS): plant-independent
+                # leaves stacked across schedules, the innermost grid axis
+                # before seeds
+                fv = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs),
+                    *[f.resolve() for f in fault_scheds])
+            else:
+                fv = faults.resolve()  # one schedule: no axis, like detector
+            gvl = (None if not guard
+                   else flt.guard_values(None if guard is True else guard))
         if typed_pi and branches != ("pi",):
             raise ValueError("typed_pi= is the single-branch fixed-gain PI "
                              f"fast path; this grid dispatches {branches}")
@@ -1339,19 +1346,19 @@ def _sweep_run(profiles: Union[str, PlantProfile,
                 f"guard={gvl is not None}, record_events={n_events > 0} — "
                 "use backend='scan'")
         max_steps = _bucket_steps(int(np.ceil(max_time / dt)))
+        P, E, A, S = len(profs), len(eps), len(pls), len(seeds)
+        W = (1 if sv is None
+             else jax.tree_util.tree_leaves(sv)[0].shape[1])
+        D = dv.shape[1] if det_grid else 1
+        F = (jax.tree_util.tree_leaves(fv)[0].shape[0] if fault_grid
+             else 1)
+        shape7 = (P, E, A, W, D, F, S)
+        n_runs = int(np.prod(shape7))
         use_exec = (backend != "scan" or chunk_size is not None
                     or devices is not None or consume is not None
                     or state is not None or stop_after is not None
                     or durable is not None)
         if use_exec:
-            P, E, A, S = len(profs), len(eps), len(pls), len(seeds)
-            W = (1 if sv is None
-                 else jax.tree_util.tree_leaves(sv)[0].shape[1])
-            D = dv.shape[1] if det_grid else 1
-            F = (jax.tree_util.tree_leaves(fv)[0].shape[0] if fault_grid
-                 else 1)
-            shape7 = (P, E, A, W, D, F, S)
-            n_runs = int(np.prod(shape7))
             with tracer.span("sweep/rows"):
                 # flatten the grid to per-run rows (grid-nest order, so the
                 # merged leading axis reshapes straight back to
@@ -1428,6 +1435,10 @@ def _sweep_run(profiles: Union[str, PlantProfile,
         if merged is None:  # consume hook ran, or stop_after cut short
             return None, exec_state
         traces, final = merged
+    if backend == "scan":
+        # the kernel op counts its own runs (native, interpret, ref)
+        from repro.kernels.closed_loop.ops import runs_counter
+        runs_counter().inc(n_runs, path="scan")
     with tracer.span("sweep/summary"):
         if use_exec:
             if backend == "pallas":

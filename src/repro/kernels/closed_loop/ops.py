@@ -46,11 +46,13 @@ def _round_up(n: int, m: int) -> int:
 
 
 def runs_counter():
-    """``closed_loop_runs_total{path}``: runs handed to `closed_loop_sim`
-    by path — ``native`` (compiled kernel), ``interpret`` or ``ref``."""
+    """``closed_loop_runs_total{path}``: runs of the closed loop by engine
+    path — ``native`` (compiled kernel), ``interpret`` or ``ref``, each
+    counted as `closed_loop_sim` takes the runs, and ``scan`` (the scan
+    engine, counted by `repro.core.sim.sweep` once it has the result)."""
     return obs_metrics.get_registry().counter(
         "closed_loop_runs_total",
-        "runs dispatched to the closed-loop kernel op, by path",
+        "runs of the closed loop, by engine path",
         labelnames=("path",))
 
 

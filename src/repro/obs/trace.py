@@ -1,10 +1,11 @@
 """Span tracing on the profiler's clock, with Chrome trace-event export.
 
 The program's spans are named ``<layer>/<part>``: ``sweep`` and its
-``sweep/grid`` (with ``sweep/keys`` and ``sweep/rows``) and
-``sweep/summary`` in `repro.core.sim`; ``plane/ingest``, ``plane/tick``
-and its ``plane/aggregate``, ``plane/pack``, ``plane/events`` and
-``plane/publish`` in `repro.core.plane`; ``signals/median`` and
+``sweep/grid`` (with ``sweep/keys``, ``sweep/scenario`` and
+``sweep/rows``) and ``sweep/summary`` in `repro.core.sim`;
+``plane/ingest``, ``plane/tick`` and its ``plane/aggregate``,
+``plane/pack``, ``plane/events`` and ``plane/publish`` in
+`repro.core.plane`; ``signals/median`` and
 ``signals/shift`` inside the aggregation (`repro.core.signals`); and
 the executor's per-chunk ``executor/prepare|compute|transfer|merge``
 (device ids in args), which nest inside whichever of those called

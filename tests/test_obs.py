@@ -445,6 +445,7 @@ def test_sweep_spans_nest_with_the_executor_s_in_one_call(tracer):
     parent = {n: es[0]["args"]["parent"] for n, es in by.items()}
     assert parent == {"sweep": None, "sweep/grid": "sweep",
                       "sweep/keys": "sweep/grid",
+                      "sweep/scenario": "sweep/grid",
                       "sweep/rows": "sweep/grid",
                       "sweep/summary": "sweep",
                       "executor/prepare": "sweep",
@@ -527,6 +528,39 @@ def test_the_scan_chunk_module_is_named_sweep_scan(monkeypatch):
 # ---------------------------------------------------------------------------
 # wiring: executor counters + spans
 # ---------------------------------------------------------------------------
+
+def _runs_by_path():
+    from repro.kernels.closed_loop.ops import runs_counter
+    c = runs_counter()
+    return {p: c.value(path=p) for p in ("scan", "interpret")}
+
+
+SCAN_GRID = dict(faults=[flt.FaultSchedule(name="noop"),
+                         flt.FaultSchedule((flt.FaultWindow(
+                             "hb_dropout", 20.0, 15.0, p1=1.0),))],
+                 guard=flt.GuardConfig())
+
+
+@pytest.mark.parametrize("path", ["one_shot", "chunked", "durable",
+                                  "kernel"])
+def test_sweep_counts_its_runs_by_engine_path(path, tmp_path):
+    """``closed_loop_runs_total{path="scan"}`` rises by the grid's runs
+    on every path through the scan engine, and not at all when the
+    kernel takes the grid (which counts under its own path)."""
+    how = {"one_shot": dict(SCAN_GRID),
+           "chunked": dict(SCAN_GRID, chunk_size=5),
+           "durable": dict(SCAN_GRID, chunk_size=5, durable=tmp_path),
+           "kernel": dict(backend="pallas")}[path]
+    before = _runs_by_path()
+    res = sweep(["gros", "dahu"], [0.1, 0.2], [1, 2, 3], total_work=50.0,
+                max_time=64.0, collect_traces=False, **how)
+    after = _runs_by_path()
+    runs = int(np.size(res.energy))
+    assert runs == (12 if path == "kernel" else 24)
+    scan, kernel = (runs, 0) if path != "kernel" else (0, runs)
+    assert after["scan"] - before["scan"] == scan
+    assert after["interpret"] - before["interpret"] == kernel
+
 
 def test_run_grid_publishes_counters_and_spans():
     import jax.numpy as jnp

@@ -178,9 +178,27 @@ def _oracle_step(profile, gains, c, total_work, max_time, dt, key):
                       steps=c.steps + (~c.done).astype(jnp.int32)), out
 
 
+# The oracle's scan and the engine are different XLA executables, and
+# XLA does not promise bit equality between two executables: fusion may
+# reorder a float32 sum (ROADMAP D0). Counts stay exact; float fields
+# agree to this many units in the last place.
+MAX_ULP = 4
+
+
+def _assert_same_trace(ref, ref_steps, res):
+    assert int(ref_steps) == res.n_steps
+    n = res.n_steps
+    for k in ("progress", "pcap", "power", "energy", "work", "t"):
+        np.testing.assert_array_max_ulp(np.asarray(ref[k][:n]),
+                                        np.asarray(res.traces[k]),
+                                        maxulp=MAX_ULP)
+
+
 def test_static_path_bit_for_bit_vs_prephases_engine():
-    """With no schedule/detector the refactored engine must reproduce
-    the pre-phases step EXACTLY — same RNG stream, same arithmetic."""
+    """With no schedule/detector the refactored engine reproduces the
+    pre-phases step — same RNG stream, same arithmetic: the same step
+    count, and every traced float within `MAX_ULP` ulp. One period's
+    key changed in the oracle fails the comparison."""
     p32 = sim._unpack_profile(sim.profile_values(PROFILES["gros"]))
     g32 = sim._unpack_gains(sim.gains_values(
         PIGains.from_model(PROFILES["gros"], 0.1)))
@@ -192,14 +210,16 @@ def test_static_path_bit_for_bit_vs_prephases_engine():
         return _oracle_step(p32, g32, c, total_work, max_time, dt, k)
 
     keys = jax.random.split(jax.random.PRNGKey(11), 512)
-    _, ref = jax.lax.scan(body, carry0, keys)
+    final, ref = jax.lax.scan(body, carry0, keys)
 
     res = simulate_closed_loop(PROFILES["gros"], 0.1, total_work=600.0,
                                max_time=512.0, seed=11)
-    n = res.n_steps
-    for k in ("progress", "pcap", "power", "energy", "work", "t"):
-        np.testing.assert_array_equal(np.asarray(ref[k][:n]),
-                                      res.traces[k], err_msg=k)
+    _assert_same_trace(ref, final.steps, res)
+
+    other = keys.at[5].set(jax.random.split(keys[5])[0])
+    final, ref = jax.lax.scan(body, carry0, other)
+    with pytest.raises(AssertionError):
+        _assert_same_trace(ref, final.steps, res)
 
 
 def test_one_phase_base_schedule_equals_static_run():
