@@ -60,11 +60,10 @@ RLS_FIELDS = ("lam", "dwell", "kl_clamp", "kl_ref", "tau_obj",
 
 
 def rls_values(cfg: RLSConfig, design: PlantProfile, gains0: PIGains
-               ) -> jnp.ndarray:
+               ) -> np.ndarray:
     tau_obj = 1.0 / (design.K_L * gains0.k_i)
-    return jnp.asarray([cfg.lam, float(cfg.dwell), cfg.kl_clamp,
-                        design.K_L, tau_obj, cfg.p_trace_max],
-                       jnp.float32)
+    return np.asarray([cfg.lam, float(cfg.dwell), cfg.kl_clamp,
+                       design.K_L, tau_obj, cfg.p_trace_max], np.float32)
 
 
 class RLSState(NamedTuple):

@@ -67,13 +67,14 @@ _DEFAULT_P1 = {"hb_dropout": 1.0, "meter_spike": 1.0}
 
 
 class FaultValues(NamedTuple):
-    """Packed fault rows, every leaf traced (scan/vmap citizens)."""
-    start: jnp.ndarray   # (R,) window start [s]
-    end: jnp.ndarray     # (R,) window end [s] (+inf on padding rows)
-    kind: jnp.ndarray    # (R,) index into FAULT_KINDS (0 = none)
-    p1: jnp.ndarray      # (R,) primary parameter (kind-specific)
-    p2: jnp.ndarray      # (R,) secondary parameter (kind-specific)
-    period: jnp.ndarray  # scalar; > 0 makes the script cyclic
+    """Packed fault rows: f32 host arrays from `FaultSchedule.resolve`,
+    every leaf traced inside the engines (scan/vmap citizens)."""
+    start: np.ndarray    # (R,) window start [s]
+    end: np.ndarray      # (R,) window end [s] (+inf on padding rows)
+    kind: np.ndarray     # (R,) index into FAULT_KINDS (0 = none)
+    p1: np.ndarray       # (R,) primary parameter (kind-specific)
+    p2: np.ndarray       # (R,) secondary parameter (kind-specific)
+    period: np.ndarray   # () scalar; > 0 makes the script cyclic
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,9 +130,8 @@ class FaultSchedule:
             kind[i] = FAULT_KINDS.index(w.kind)
             p1[i] = w.p1 if w.p1 else _DEFAULT_P1.get(w.kind, 0.0)
             p2[i] = w.p2
-        return FaultValues(jnp.asarray(start), jnp.asarray(end),
-                           jnp.asarray(kind), jnp.asarray(p1),
-                           jnp.asarray(p2), jnp.float32(self.period))
+        return FaultValues(start, end, kind, p1, p2,
+                           np.asarray(self.period, np.float32))
 
     # host-side view (FaultyActuator + tests)
     def active(self, t: float) -> Tuple[FaultWindow, ...]:
@@ -240,11 +240,11 @@ class GuardConfig:
     recover_reset: bool = True
 
 
-def guard_values(cfg: Optional[GuardConfig] = None) -> jnp.ndarray:
+def guard_values(cfg: Optional[GuardConfig] = None) -> np.ndarray:
     cfg = cfg or GuardConfig()
-    return jnp.array([cfg.hold_k, cfg.failsafe_k, cfg.outlier_mult,
-                      1.0 if cfg.recover_reset else 0.0, 0.0, 0.0],
-                     jnp.float32)
+    return np.array([cfg.hold_k, cfg.failsafe_k, cfg.outlier_mult,
+                     1.0 if cfg.recover_reset else 0.0, 0.0, 0.0],
+                    np.float32)
 
 
 GUARD_STATE_DIM = 8

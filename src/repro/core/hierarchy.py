@@ -338,15 +338,15 @@ def _fleet_args(profile, fc: FleetConfig, node_class, policies,
     node_pols = _fleet_policies(policies, len(profs), n, cls)
     branches, kinds = pol.resolve_kinds(node_pols)
 
-    pv = np.stack([np.asarray(sim.profile_values(p)) for p in profs])[cls]
-    gv = np.stack([np.asarray(sim.gains_values(g)) for g in gains])[cls]
+    pv = np.stack([sim.profile_values(p) for p in profs])[cls]
+    gv = np.stack([sim.gains_values(g) for g in gains])[cls]
     av = np.zeros((n, pol.POLICY_PARAM_DIM), np.float32)
     cache = {}
     for i, (p_, k_) in enumerate(zip(node_pols, kinds)):
         ck = (int(cls[i]), p_, k_)
         if ck not in cache:
-            cache[ck] = np.asarray(pol.policy_values(
-                p_, profs[cls[i]], gains[cls[i]], kind=k_))
+            cache[ck] = pol.policy_values(
+                p_, profs[cls[i]], gains[cls[i]], kind=k_)
         av[i] = cache[ck]
     sv = _fleet_schedules(schedules, profs, n, cls)
     return profs, cls, branches, (jnp.asarray(pv), jnp.asarray(gv),
@@ -423,10 +423,8 @@ def _simulate_fleet_reference(profile, fc: FleetConfig, steps: int,
     profs, cls = _fleet_layout(profile, fc, node_class)
     n = fc.n_nodes
     gains = [PIGains.from_model(p, fc.epsilon, fc.tau_obj) for p in profs]
-    pv = jnp.asarray(np.stack([np.asarray(sim.profile_values(p))
-                               for p in profs])[cls])
-    gv = jnp.asarray(np.stack([np.asarray(sim.gains_values(g))
-                               for g in gains])[cls])
+    pv = jnp.asarray(np.stack([sim.profile_values(p) for p in profs])[cls])
+    gv = jnp.asarray(np.stack([sim.gains_values(g) for g in gains])[cls])
     class_ids = jnp.asarray(cls, jnp.int32)
     n_classes = len(profs)
     lo, hi = pv[:, _F_PCAP_MIN], pv[:, _F_PCAP_MAX]

@@ -66,10 +66,11 @@ GAIN_FIELDS = ("k_p", "k_i", "setpoint", "pcap_min", "pcap_max",
 GAIN_DIM = len(GAIN_FIELDS)
 
 
-def gains_values(gains: PIGains) -> jnp.ndarray:
-    """Pack a PIGains into the canonical traced (GAIN_DIM,) f32 vector."""
-    return jnp.asarray([getattr(gains, f) for f in GAIN_FIELDS],
-                       jnp.float32)
+def gains_values(gains: PIGains) -> np.ndarray:
+    """Pack a PIGains into the canonical (GAIN_DIM,) f32 host vector,
+    which the engines trace."""
+    return np.asarray([getattr(gains, f) for f in GAIN_FIELDS],
+                      np.float32)
 
 
 def unpack_gains(vals) -> PIGains:
@@ -425,12 +426,11 @@ class ControlPlane:
         # dead rows still flow through the vmapped tick: give them the
         # default profile's context so their (discarded) math stays
         # finite instead of 0-division garbage
-        g = np.asarray(gains_values(
-            PIGains.from_model(self.profile, self.epsilon)))
-        self._gains[:] = g
-        self._dvals[:] = np.asarray(detector_values(
-            self.detector or DetectorConfig(), self.profile))
-        self._gvals[:] = np.asarray(flt.guard_values(self.guard))
+        self._gains[:] = gains_values(
+            PIGains.from_model(self.profile, self.epsilon))
+        self._dvals[:] = detector_values(
+            self.detector or DetectorConfig(), self.profile)
+        self._gvals[:] = flt.guard_values(self.guard)
         self._pcap[:] = self.profile.pcap_max
         self._free = [i for i in range(cap) if not self._alive[i]]
 
@@ -512,23 +512,21 @@ class ControlPlane:
         gains = PIGains.from_model(prof, eps)
         p = policy if policy is not None else PIPolicy()
         kind = self._kind(p.branch)
-        pvals = np.asarray(pol.policy_values(p, prof, gains, kind=kind),
-                           np.float32)
+        pvals = pol.policy_values(p, prof, gains, kind=kind)
         pstate = np.asarray(pol.branch_init(self._branches)(
             jnp.asarray(pvals), gains), np.float32)
         det_cfg = (self.detector if detector is None
                    else None if detector is False
                    else DetectorConfig() if detector is True
                    else detector)
-        dvals = np.asarray(detector_values(det_cfg or DetectorConfig(),
-                                           prof), np.float32)
+        dvals = detector_values(det_cfg or DetectorConfig(), prof)
         dstate = np.asarray(detect_init(jnp.asarray(dvals), gains),
                             np.float32)
         guard_cfg = (self.guard if guard is None
                      else None if guard is False
                      else flt.GuardConfig() if guard is True
                      else guard)
-        gvec = np.asarray(gains_values(gains), np.float32)
+        gvec = gains_values(gains)
         if len(self._free) < n:
             self._grow(self.capacity - len(self._free) + n)
         slots = np.asarray([self._free.pop(0) for _ in range(n)])
@@ -544,8 +542,7 @@ class ControlPlane:
         self._dvals[slots] = dvals
         self._dstate[slots] = dstate
         self._det_on[slots] = 0.0 if det_cfg is None else 1.0
-        self._gvals[slots] = np.asarray(flt.guard_values(guard_cfg),
-                                        np.float32)
+        self._gvals[slots] = flt.guard_values(guard_cfg)
         self._gstate[slots] = np.asarray(flt.guard_init(), np.float32)
         self._guard_on[slots] = 0.0 if guard_cfg is None else 1.0
         self._pcap[slots] = prof.pcap_max

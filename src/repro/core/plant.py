@@ -21,6 +21,7 @@ simulated fleet (repro.core.hierarchy).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -62,8 +63,12 @@ class PlantProfile:
         power = self.power_of_pcap(pcap)
         return self.K_L * (1.0 - jnp.exp(-self.alpha * (power - self.beta)))
 
-    @property
+    @functools.cached_property
     def progress_max(self) -> float:
+        """Static progress at pcap_max, one float32 device evaluation
+        per profile: the profile is frozen, so the first value is kept
+        and every later read (each `PIGains.from_model` of a grid) is a
+        host attribute lookup."""
         return float(self.static_progress(self.pcap_max))
 
 

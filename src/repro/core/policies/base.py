@@ -9,10 +9,11 @@ scan engine (`repro.core.sim`) dispatches through, instead of a fork of
 Contract (all pure JAX, vmap/scan-safe):
 
 * ``policy_values(policy, profile, gains) -> (POLICY_PARAM_DIM,) f32`` —
-  the policy's hyperparameters packed into a fixed-width TRACED vector
-  (slot 0 is the dispatch kind, assigned by the caller for heterogeneous
-  grids). Because params are traced, hyperparameter grids vmap without
-  recompiling.
+  the policy's hyperparameters packed on the host into a fixed-width
+  numpy vector that the engine traces (slot 0 is the dispatch kind,
+  assigned by the caller for heterogeneous grids). Packing makes no
+  device call; because params are traced, hyperparameter grids vmap
+  without recompiling.
 * ``policy_init(policy, vals, gains) -> (POLICY_STATE_DIM,) f32`` — the
   policy's initial state packed into a fixed-width vector. A UNIFORM
   state width is what lets heterogeneous policies share one compiled
@@ -42,6 +43,7 @@ from typing import Callable, Dict, NamedTuple, Optional, Sequence, Tuple, \
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.controller import PIGains
 from repro.core.plant import PlantProfile
@@ -112,17 +114,15 @@ class Policy:
     def branch(self) -> str:
         raise NotImplementedError
 
-    def values(self, profile: PlantProfile, gains: PIGains) -> jnp.ndarray:
+    def values(self, profile: PlantProfile, gains: PIGains) -> np.ndarray:
         """Policy hyperparameters at slots [1:]; slot 0 (kind) is left 0."""
-        return jnp.zeros((POLICY_PARAM_DIM,), jnp.float32)
+        return np.zeros((POLICY_PARAM_DIM,), np.float32)
 
 
-def pack_values(*params) -> jnp.ndarray:
-    """Pack params into slots [1:1+len] of a zeroed PARAM vector."""
-    v = jnp.zeros((POLICY_PARAM_DIM,), jnp.float32)
-    if params:
-        v = v.at[1:1 + len(params)].set(
-            jnp.asarray(params, jnp.float32))
+def pack_values(*params) -> np.ndarray:
+    """Pack params into slots [1:1+len] of a zeroed PARAM host vector."""
+    v = np.zeros((POLICY_PARAM_DIM,), np.float32)
+    v[1:1 + len(params)] = np.asarray(params, np.float32)
     return v
 
 
@@ -140,10 +140,13 @@ def as_branches(policy: BranchSpec) -> Tuple[str, ...]:
 
 
 def policy_values(policy: Policy, profile: PlantProfile, gains: PIGains,
-                  kind: int = 0) -> jnp.ndarray:
-    """The contract's `policy_values`: traced param vector with the
-    dispatch kind (index into the active branch tuple) at slot 0."""
-    return policy.values(profile, gains).at[0].set(float(kind))
+                  kind: int = 0) -> np.ndarray:
+    """The contract's `policy_values`: the f32 host param vector, with
+    the dispatch kind (index into the active branch tuple) at slot 0. A
+    `Policy.values` that returns a `jax.Array` is copied to the host."""
+    v = np.array(policy.values(profile, gains), np.float32)
+    v[0] = kind
+    return v
 
 
 def branch_tag(name: str) -> int:

@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.controller import PIGains
 from repro.core.plant import PlantProfile
@@ -63,6 +64,6 @@ class DutyCyclePolicy(Policy):
     def branch(self) -> str:
         return "dutycycle"
 
-    def values(self, profile: PlantProfile, gains: PIGains) -> jnp.ndarray:
+    def values(self, profile: PlantProfile, gains: PIGains) -> np.ndarray:
         return pack_values(float(self.n_levels), float(self.min_level),
                            self.deadband, self.down_step, self.up_step)

@@ -80,7 +80,7 @@ class OfflineRLPolicy(Policy):
     def branch(self) -> str:
         return "offline_rl"
 
-    def values(self, profile: PlantProfile, gains: PIGains) -> jnp.ndarray:
+    def values(self, profile: PlantProfile, gains: PIGains) -> np.ndarray:
         if len(self.weights) != N_FEATURES:
             raise ValueError(f"OfflineRLPolicy needs {N_FEATURES} feature "
                              f"weights, got {len(self.weights)}")

@@ -19,6 +19,7 @@ import dataclasses
 from typing import Optional
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.adaptive import (RLSConfig, rls_init, rls_pack, rls_step,
                                  rls_unpack, rls_values)
@@ -137,8 +138,8 @@ class PIPolicy(Policy):
     def branch(self) -> str:
         return "pi_rls" if self.adaptive is not None else "pi"
 
-    def values(self, profile: PlantProfile, gains: PIGains) -> jnp.ndarray:
+    def values(self, profile: PlantProfile, gains: PIGains) -> np.ndarray:
         if self.adaptive is None:
             return pack_values()
-        rv = rls_values(self.adaptive, self.design or profile, gains)
-        return pack_values(*[rv[i] for i in range(6)])
+        return pack_values(*rls_values(self.adaptive,
+                                       self.design or profile, gains))

@@ -142,9 +142,11 @@ CAP_BINS = 32
 PROG_HIST_SPAN = 1.5
 
 
-def profile_values(profile: PlantProfile) -> jnp.ndarray:
-    return jnp.asarray([getattr(profile, f) for f in _PROFILE_FIELDS],
-                       jnp.float32)
+def profile_values(profile: PlantProfile) -> np.ndarray:
+    """Pack a profile into the canonical (len(PROFILE_FIELDS),) f32 host
+    vector, which the engines trace."""
+    return np.asarray([getattr(profile, f) for f in _PROFILE_FIELDS],
+                      np.float32)
 
 
 def _unpack_profile(vals) -> PlantProfile:
@@ -1165,6 +1167,101 @@ def simulate_closed_loop(profile: Union[str, PlantProfile],
                                   else np.asarray(final.events)))
 
 
+class _GridValues(NamedTuple):
+    """A sweep's parameter tables, host float32 throughout: the engines
+    trace them, and they cross to the device with the engine's inputs
+    (the executor's per-chunk transfer, or the one-shot jit call)."""
+    pv: np.ndarray                  # (P, len(PROFILE_FIELDS))
+    gv: np.ndarray                  # (P, E, GAIN_DIM)
+    av: np.ndarray                  # (P, A, POLICY_PARAM_DIM)
+    sv: Optional[ScheduleValues]    # leaves (P, W, ...)
+    dv: Optional[np.ndarray]        # (P, [D,] DET_PARAM_DIM)
+    fv: Optional[flt.FaultValues]   # leaves ([F,] ...)
+    gvl: Optional[np.ndarray]       # packed GuardConfig
+    squeeze_w: Optional[bool]       # one PhaseSchedule: no W axis kept
+    det_grid: bool                  # a D axis of DetectorConfigs
+    fault_grid: bool                # an F axis of FaultSchedules
+
+
+def _grid_values(profs: Sequence[PlantProfile], eps: Sequence[float],
+                 pls: Sequence[pol.Policy], kinds: Sequence[int],
+                 tau_obj: float, workloads=None, detector=None,
+                 faults=None, guard=None) -> _GridValues:
+    """Pack a sweep grid's tables on the host (numpy, no device call):
+    gains per (profile, epsilon), policy values per (profile, policy) at
+    the eps[0] design point, and the scenario (``sweep/scenario``):
+    phase schedules resolved per profile, detector values, fault rows,
+    the guard vector. Each value is a Python float rounded to float32
+    once."""
+    pv = np.stack([profile_values(p) for p in profs])
+    gv = np.stack([
+        np.stack([gains_values(PIGains.from_model(p, e, tau_obj))
+                  for e in eps]) for p in profs])
+    # policy values grid (P, A, PARAM_DIM), built at the eps[0] design
+    # point per profile (cf. the adaptive grid: kl_ref/tau_obj depend
+    # only on the profile)
+    av = np.stack([
+        np.stack([pol.policy_values(
+            p_, p, PIGains.from_model(p, eps[0], tau_obj), kind=k)
+            for p_, k in zip(pls, kinds)]) for p in profs])
+    with obs_trace.get_tracer().span("sweep/scenario"):
+        if workloads is None:
+            sv, squeeze_w = None, None
+        else:
+            squeeze_w = isinstance(workloads, PhaseSchedule)
+            wls = [workloads] if squeeze_w else list(workloads)
+            if not wls:
+                raise ValueError("workloads= needs at least one "
+                                 "PhaseSchedule")
+            # schedule leaves stacked (P, W, ...): resolved per profile,
+            # all packed to the grid's common row count (piecewise
+            # chaining keeps long scripts in whole 16-row pieces)
+            rows = max(chain_rows(len(w.phases)) for w in wls)
+            sv = jax.tree_util.tree_map(
+                lambda *xs: np.stack(xs),
+                *[jax.tree_util.tree_map(
+                    lambda *ws: np.stack(ws),
+                    *[w.resolve(p, rows) for w in wls])
+                  for p in profs])
+        det_grid = (detector is not None
+                    and not isinstance(detector, DetectorConfig))
+        if detector is None:
+            dv = None
+        elif det_grid:
+            det_cfgs = list(detector)
+            if not det_cfgs:
+                raise ValueError("detector= needs at least one "
+                                 "DetectorConfig")
+            # detector hyperparameter grid (P, D, DET_PARAM_DIM): a new D
+            # axis between [workloads] and seeds, like the adaptive= grid
+            dv = np.stack([np.stack([detector_values(d, p)
+                                     for d in det_cfgs])
+                           for p in profs])
+        else:
+            dv = np.stack([detector_values(detector, p) for p in profs])
+        fault_grid = (faults is not None
+                      and not isinstance(faults, flt.FaultSchedule))
+        if faults is None:
+            fv = None
+        elif fault_grid:
+            fault_scheds = list(faults)
+            if not fault_scheds:
+                raise ValueError("faults= needs at least one "
+                                 "FaultSchedule")
+            # fault-scenario axis (F, MAX_FAULT_ROWS): plant-independent
+            # leaves stacked across schedules, the innermost grid axis
+            # before seeds
+            fv = jax.tree_util.tree_map(
+                lambda *xs: np.stack(xs),
+                *[f.resolve() for f in fault_scheds])
+        else:
+            fv = faults.resolve()  # one schedule: no axis, like detector
+        gvl = (None if not guard
+               else flt.guard_values(None if guard is True else guard))
+    return _GridValues(pv, gv, av, sv, dv, fv, gvl, squeeze_w, det_grid,
+                       fault_grid)
+
+
 def _sweep_impl(*args, **kwargs):
     """Shared implementation behind `sweep` / `sweep_resumable`, traced
     as one ``sweep`` span (`repro.obs.trace`); `_sweep_run` does the
@@ -1238,75 +1335,12 @@ def _sweep_run(profiles: Union[str, PlantProfile,
             if not pls:
                 raise ValueError("policies= needs at least one Policy")
         branches, kinds = pol.resolve_kinds(pls)
-        pv = jnp.stack([profile_values(p) for p in profs])
-        gv = jnp.stack([
-            jnp.stack([gains_values(PIGains.from_model(p, e, tau_obj))
-                       for e in eps]) for p in profs])
         with tracer.span("sweep/keys"):
             keys = seed_keys(seeds)
-        # policy values grid (P, A, PARAM_DIM), built at the eps[0] design
-        # point per profile (cf. the adaptive grid: kl_ref/tau_obj depend
-        # only on the profile)
-        av = jnp.stack([
-            jnp.stack([pol.policy_values(
-                p_, p, PIGains.from_model(p, eps[0], tau_obj), kind=k)
-                for p_, k in zip(pls, kinds)]) for p in profs])
-        with tracer.span("sweep/scenario"):
-            if workloads is None:
-                sv, squeeze_w = None, None
-            else:
-                squeeze_w = isinstance(workloads, PhaseSchedule)
-                wls = [workloads] if squeeze_w else list(workloads)
-                if not wls:
-                    raise ValueError("workloads= needs at least one "
-                                     "PhaseSchedule")
-                # schedule leaves stacked (P, W, ...): resolved per
-                # profile, all packed to the grid's common row count
-                # (piecewise chaining keeps long scripts in whole 16-row
-                # pieces)
-                rows = max(chain_rows(len(w.phases)) for w in wls)
-                sv = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs),
-                    *[jax.tree_util.tree_map(
-                        lambda *ws: jnp.stack(ws),
-                        *[w.resolve(p, rows) for w in wls])
-                      for p in profs])
-            det_grid = (detector is not None
-                        and not isinstance(detector, DetectorConfig))
-            if detector is None:
-                dv = None
-            elif det_grid:
-                det_cfgs = list(detector)
-                if not det_cfgs:
-                    raise ValueError("detector= needs at least one "
-                                     "DetectorConfig")
-                # detector hyperparameter grid (P, D, DET_PARAM_DIM): a new
-                # D axis between [workloads] and seeds, like the adaptive=
-                # grid
-                dv = jnp.stack([jnp.stack([detector_values(d, p)
-                                           for d in det_cfgs])
-                                for p in profs])
-            else:
-                dv = jnp.stack([detector_values(detector, p) for p in profs])
-            fault_grid = (faults is not None
-                          and not isinstance(faults, flt.FaultSchedule))
-            if faults is None:
-                fv = None
-            elif fault_grid:
-                fault_scheds = list(faults)
-                if not fault_scheds:
-                    raise ValueError("faults= needs at least one "
-                                     "FaultSchedule")
-                # fault-scenario axis (F, MAX_FAULT_ROWS): plant-independent
-                # leaves stacked across schedules, the innermost grid axis
-                # before seeds
-                fv = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs),
-                    *[f.resolve() for f in fault_scheds])
-            else:
-                fv = faults.resolve()  # one schedule: no axis, like detector
-            gvl = (None if not guard
-                   else flt.guard_values(None if guard is True else guard))
+        (pv, gv, av, sv, dv, fv, gvl, squeeze_w, det_grid,
+         fault_grid) = _grid_values(profs, eps, pls, kinds, tau_obj,
+                                    workloads=workloads, detector=detector,
+                                    faults=faults, guard=guard)
         if typed_pi and branches != ("pi",):
             raise ValueError("typed_pi= is the single-branch fixed-gain PI "
                              f"fast path; this grid dispatches {branches}")
@@ -1365,24 +1399,21 @@ def _sweep_run(profiles: Union[str, PlantProfile,
                 # (P,E,A,[W],[D],[F],S))
                 (ip, ie, ia, iw, idet, ifl,
                  is_) = np.indices(shape7).reshape(7, n_runs)
-                batched = {"prof": np.asarray(pv)[ip],
-                           "gains": np.asarray(gv)[ip, ie],
-                           "pvals": np.asarray(av)[ip, ia],
-                           "key": keys[is_]}
+                batched = {"prof": pv[ip], "gains": gv[ip, ie],
+                           "pvals": av[ip, ia], "key": keys[is_]}
                 if sv is not None:
                     batched["sched"] = jax.tree_util.tree_map(
-                        lambda x: np.asarray(x)[ip, iw], sv)
+                        lambda x: x[ip, iw], sv)
                 if dv is not None:
-                    batched["det"] = (np.asarray(dv)[ip, idet] if det_grid
-                                      else np.asarray(dv)[ip])
+                    batched["det"] = dv[ip, idet] if det_grid else dv[ip]
                 if fv is not None:
                     # fault rows always ride the per-run rows here (a single
                     # schedule broadcasts), so chunk slicing stays uniform
                     batched["faults"] = jax.tree_util.tree_map(
-                        lambda x: (np.asarray(x)[ifl] if fault_grid
+                        lambda x: (x[ifl] if fault_grid
                                    else np.broadcast_to(
-                                       np.asarray(x),
-                                       (n_runs,) + np.shape(x)).copy()), fv)
+                                       x, (n_runs,) + np.shape(x)).copy()),
+                        fv)
             if backend == "pallas":
                 # the op jits itself; with devices= the executor places one
                 # slice of every chunk on each device
@@ -1394,8 +1425,8 @@ def _sweep_run(profiles: Union[str, PlantProfile,
                 fn = _flat_core(max_steps, branches, collect_traces,
                                 sv is not None, dv is not None, typed_pi,
                                 gvl is not None, n_events)
-                shared = (jnp.float32(total_work), jnp.float32(max_time),
-                          jnp.float32(dt), jnp.float32(summary_warmup))
+                shared = (np.float32(total_work), np.float32(max_time),
+                          np.float32(dt), np.float32(summary_warmup))
                 if gvl is not None:
                     shared = shared + (gvl,)
                 wrap = "jit"

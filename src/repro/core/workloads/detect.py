@@ -74,12 +74,13 @@ class DetectorConfig:
 
 
 def detector_values(cfg: DetectorConfig, design: PlantProfile
-                    ) -> jnp.ndarray:
-    """Pack (config, design model) -> traced (len(DET_PARAM_FIELDS),)."""
+                    ) -> np.ndarray:
+    """Pack (config, design model) -> (len(DET_PARAM_FIELDS),) f32 host
+    vector, which the engines trace."""
     noise_ref = design.noise_scale * float(np.sqrt(design.n_sockets))
-    return jnp.asarray([design.K_L, design.tau, noise_ref, cfg.drift,
-                        cfg.threshold, float(cfg.min_gap),
-                        cfg.level_eta, cfg.level_slack], jnp.float32)
+    return np.asarray([design.K_L, design.tau, noise_ref, cfg.drift,
+                       cfg.threshold, float(cfg.min_gap),
+                       cfg.level_eta, cfg.level_slack], np.float32)
 
 
 def detect_init(vals, gains, pcap0=None) -> jnp.ndarray:

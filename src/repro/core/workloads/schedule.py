@@ -59,7 +59,8 @@ def chain_rows(n_phases: int) -> int:
 
 
 class ScheduleValues(NamedTuple):
-    """Packed traced form of a PhaseSchedule (the engine-facing contract).
+    """Packed form of a PhaseSchedule (the engine-facing contract): f32
+    host arrays from `PhaseSchedule.resolve`, traced inside the engines.
 
     ``ends`` is the cumulative end time of each phase (+inf padding past
     the last scripted phase); ``profiles`` the per-phase plant rows in
@@ -67,9 +68,9 @@ class ScheduleValues(NamedTuple):
     cycle length in seconds, 0 for non-cyclic schedules. ``rows`` is
     `chain_rows` of the phase count — every schedule in one grid packs
     to a common row count (`PhaseSchedule.resolve(rows=...)`)."""
-    ends: jnp.ndarray      # (rows,) f32
-    profiles: jnp.ndarray  # (rows, len(PROFILE_FIELDS)) f32
-    period: jnp.ndarray    # f32 scalar; 0 = hold the last phase forever
+    ends: np.ndarray      # (rows,) f32
+    profiles: np.ndarray  # (rows, len(PROFILE_FIELDS)) f32
+    period: np.ndarray    # () f32; 0 = hold the last phase forever
 
 
 def active_profile(sched: ScheduleValues, t):
@@ -146,7 +147,8 @@ class PhaseSchedule:
 
     def resolve(self, base: Union[str, PlantProfile],
                 rows: Optional[int] = None) -> ScheduleValues:
-        """Pack against a base profile -> engine-facing traced arrays.
+        """Pack against a base profile -> engine-facing f32 host arrays
+        (no device call).
 
         ``rows`` overrides the packed row count (must be a whole number
         of MAX_PHASES pieces >= the phase count): grids stacking short
@@ -172,9 +174,8 @@ class PhaseSchedule:
         else:
             period = 0.0
             ends[n - 1] = np.inf  # hold the last phase forever
-        return ScheduleValues(ends=jnp.asarray(ends),
-                              profiles=jnp.asarray(rows_),
-                              period=jnp.float32(period))
+        return ScheduleValues(ends=ends, profiles=rows_,
+                              period=np.asarray(period, np.float32))
 
 
 # ---- generators -----------------------------------------------------------

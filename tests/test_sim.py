@@ -1,7 +1,9 @@
 """Scan engine (repro.core.sim): equivalence with the stateful NRM loop,
 the in-scan RLS estimator vs its numpy oracle, trace-free summary mode
 vs full-trace reductions, vmapped sweep shapes/correctness, the
-host-built seed keys vs PRNGKey, and the Eq. 3 replay helper."""
+host-built seed keys vs PRNGKey, the Eq. 3 replay helper, and the
+grid tables packed on the host (bit-equal to device packing, no device
+call while the grid is built, no new engine on a second sweep)."""
 import dataclasses
 
 import jax
@@ -10,13 +12,19 @@ import numpy as np
 import pytest
 
 from repro.configs.base import PowerControlConfig
-from repro.core import sim
+from repro.core import executor, plane, sim
+from repro.core import faults as flt
+from repro.core import policies as pol
 from repro.core.adaptive import RLSAdapter, RLSConfig
 from repro.core.controller import PIGains
 from repro.core.nrm import NRM
-from repro.core.plant import PROFILES, pcap_linearize, simulate
+from repro.core.plant import (PROFILE_FIELDS, PROFILES, PlantProfile,
+                              pcap_linearize, simulate)
+from repro.core.policies import DutyCyclePolicy, OfflineRLPolicy, PIPolicy
 from repro.core.sim import (hist_quantile, open_loop_runs, replay_model,
                             seed_keys, simulate_closed_loop, sweep)
+from repro.core.workloads.detect import DetectorConfig, detector_values
+from repro.core.workloads.schedule import MAX_PHASES, Phase, PhaseSchedule
 
 
 @pytest.mark.parametrize("name", ["gros", "dahu"])
@@ -466,3 +474,213 @@ def test_compilation_cache_location(monkeypatch):
             root / "experiments" / "xla_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+# ---------------------------------------------------------------------------
+# The sweep's grid tables are packed on the host. Oracles: the device
+# expressions the packers used before (jnp.asarray / .at[].set), rebuilt
+# here; every table must come out bit-identical.
+# ---------------------------------------------------------------------------
+
+FIG7_EPS = (0.0, 0.01, 0.02, 0.05, 0.08, 0.1, 0.15, 0.2, 0.3, 0.4, 0.5)
+GRID_POLICIES = {
+    "pi": PIPolicy(),
+    "pi_rls": PIPolicy(adaptive=RLSConfig()),
+    "dutycycle": DutyCyclePolicy(),
+    "offline_rl": OfflineRLPolicy(weights=(0.1, -1.3, 2.7, 1e-3, -0.45,
+                                           3.3)),
+}
+
+
+def _phased_scenario():
+    """The phased cell's scenario: cyclic STREAM/DGEMM knees, the
+    detector, a 10% heartbeat blackout with a frozen meter, the guard."""
+    return dict(
+        workloads=PhaseSchedule((
+            Phase(100.0, scale=(("alpha", 3.0), ("beta", 0.6))),
+            Phase(100.0, scale=(("alpha", 0.3), ("beta", 1.14)))),
+            cyclic=True),
+        detector=DetectorConfig(),
+        faults=flt.FaultSchedule((
+            flt.FaultWindow("hb_dropout", 80.0, 40.0, p1=1.0),
+            flt.FaultWindow("meter_freeze", 80.0, 40.0)), period=400.0),
+        guard=flt.GuardConfig())
+
+
+def _jnp_pack(kind, params=()):
+    v = jnp.zeros((pol.POLICY_PARAM_DIM,), jnp.float32)
+    if params:
+        v = v.at[1:1 + len(params)].set(jnp.asarray(params, jnp.float32))
+    return v.at[0].set(float(kind))
+
+
+def _jnp_policy_values(policy, prof, gains, kind):
+    if isinstance(policy, DutyCyclePolicy):
+        return _jnp_pack(kind, (float(policy.n_levels),
+                                float(policy.min_level), policy.deadband,
+                                policy.down_step, policy.up_step))
+    if isinstance(policy, OfflineRLPolicy):
+        return _jnp_pack(kind, policy.weights)
+    if policy.adaptive is None:
+        return _jnp_pack(kind)
+    cfg = policy.adaptive
+    rv = jnp.asarray([cfg.lam, float(cfg.dwell), cfg.kl_clamp, prof.K_L,
+                      1.0 / (prof.K_L * gains.k_i), cfg.p_trace_max],
+                     jnp.float32)
+    return _jnp_pack(kind, [rv[i] for i in range(6)])
+
+
+def _assert_host_equal(got, want):
+    assert isinstance(got, np.ndarray) and got.dtype == np.float32
+    assert got.shape == np.shape(want)
+    assert np.array_equal(got, np.asarray(want))
+
+
+@pytest.mark.parametrize("policy", list(GRID_POLICIES))
+@pytest.mark.parametrize("name", list(PROFILES))
+def test_host_tables_bit_equal_to_device_packing(name, policy):
+    prof = PROFILES[name]
+    p = GRID_POLICIES[policy]
+    kind = list(GRID_POLICIES).index(policy)
+    assert prof.progress_max == float(prof.static_progress(prof.pcap_max))
+    _assert_host_equal(sim.profile_values(prof), jnp.asarray(
+        [getattr(prof, f) for f in PROFILE_FIELDS], jnp.float32))
+    for eps in FIG7_EPS:
+        g = PIGains.from_model(prof, eps, 10.0)
+        _assert_host_equal(sim.gains_values(g), jnp.asarray(
+            [getattr(g, f) for f in plane.GAIN_FIELDS], jnp.float32))
+        _assert_host_equal(pol.policy_values(p, prof, g, kind=kind),
+                           _jnp_policy_values(p, prof, g, kind))
+    det = DetectorConfig()
+    _assert_host_equal(detector_values(det, prof), jnp.asarray(
+        [prof.K_L, prof.tau, prof.noise_scale * float(np.sqrt(
+            prof.n_sockets)), det.drift, det.threshold,
+         float(det.min_gap), det.level_eta, det.level_slack],
+        jnp.float32))
+    gcfg = flt.GuardConfig(hold_k=4, recover_reset=False)
+    _assert_host_equal(flt.guard_values(gcfg), jnp.array(
+        [4, gcfg.failsafe_k, gcfg.outlier_mult, 0.0, 0.0, 0.0],
+        jnp.float32))
+    scen = _phased_scenario()
+    sched = scen["workloads"]
+    sv = sched.resolve(prof)
+    _assert_host_equal(sv.ends, jnp.asarray(np.concatenate([
+        np.cumsum([ph.duration for ph in sched.phases]),
+        np.full(MAX_PHASES - len(sched.phases), np.inf)]), jnp.float32))
+    rows = [jnp.asarray([getattr(ph.resolve(prof), f)
+                         for f in PROFILE_FIELDS], jnp.float32)
+            for ph in sched.phases]
+    _assert_host_equal(sv.profiles, jnp.stack(
+        rows + [rows[-1]] * (MAX_PHASES - len(rows))))
+    _assert_host_equal(sv.period, jnp.float32(200.0))
+    fs = scen["faults"]
+    fv = fs.resolve()
+    pad = [np.inf] * (flt.MAX_FAULT_ROWS - len(fs.windows))
+    zeros = [0.0] * len(pad)
+    want = flt.FaultValues(
+        jnp.asarray([w.start for w in fs.windows] + pad, jnp.float32),
+        jnp.asarray([w.start + w.duration for w in fs.windows] + pad,
+                    jnp.float32),
+        jnp.asarray([flt.FAULT_KINDS.index(w.kind) for w in fs.windows]
+                    + zeros, jnp.float32),
+        jnp.asarray([1.0, 0.0] + zeros, jnp.float32),
+        jnp.asarray([w.p2 for w in fs.windows] + zeros, jnp.float32),
+        jnp.float32(fs.period))
+    for got, w in zip(fv, want):
+        _assert_host_equal(got, w)
+
+
+GRIDS = {  # epsilons, policies, scenario of the two campaign cells
+    "fixed_pi": (FIG7_EPS, ("pi",), False),
+    "phased_faulted": ((0.1, 0.3), ("pi", "pi_rls", "dutycycle"), True),
+}
+
+
+@pytest.mark.parametrize("grid", list(GRIDS))
+def test_grid_values_make_no_device_call(grid):
+    """The grid tables of both campaign cells build with every host <->
+    device transfer forbidden: nothing in them touches the device."""
+    eps, names, phased = GRIDS[grid]
+    scen = _phased_scenario() if phased else {}
+    profs = [PROFILES[n] for n in ("gros", "dahu", "yeti")]
+    pls = [GRID_POLICIES[n] for n in names]
+    _, kinds = pol.resolve_kinds(pls)
+    for p in profs:
+        p.progress_max  # its one device evaluation, kept on the profile
+    with jax.transfer_guard("disallow"):
+        g = sim._grid_values(profs, eps, pls, kinds, 10.0, **scen)
+    assert g.pv.shape == (3, len(PROFILE_FIELDS))
+    assert g.gv.shape == (3, len(eps), plane.GAIN_DIM)
+    assert g.av.shape == (3, len(pls), pol.POLICY_PARAM_DIM)
+    for leaf in jax.tree_util.tree_leaves(g[:7]):
+        assert isinstance(leaf, np.ndarray) and leaf.dtype == np.float32
+    assert (g.sv is None) == (not phased)
+
+
+def test_progress_max_evaluated_once_per_profile(monkeypatch):
+    calls = []
+    static = PlantProfile.static_progress
+
+    def counted(self, pcap):
+        calls.append(self.name)
+        return static(self, pcap)
+
+    monkeypatch.setattr(PlantProfile, "static_progress", counted)
+    profs = [dataclasses.replace(PROFILES[n]) for n in ("gros", "dahu",
+                                                         "yeti")]
+    for _ in range(2):
+        for p in profs:
+            for eps in FIG7_EPS:
+                PIGains.from_model(p, eps, 10.0)
+        sim._grid_values(profs, FIG7_EPS, [PIPolicy()], (0,), 10.0)
+    assert sorted(calls) == ["dahu", "gros", "yeti"]
+    for p, n in zip(profs, ("gros", "dahu", "yeti")):
+        assert p.progress_max == PROFILES[n].progress_max
+
+
+def _phased_sweep(seeds):
+    return sweep([PROFILES[n] for n in ("gros", "dahu", "yeti")],
+                 [0.1, 0.3], seeds, total_work=400.0, max_time=40.0,
+                 policies=[GRID_POLICIES[n]
+                           for n in ("pi", "pi_rls", "dutycycle")],
+                 collect_traces=False, backend="scan", chunk_size=12,
+                 **_phased_scenario())
+
+
+def _engine_cache_sizes():
+    return {k: v._cache_size() for k, v in executor._COMPILED.items()
+            if hasattr(v, "_cache_size")}
+
+
+def test_second_scan_sweep_adds_no_compiled_engine():
+    first = _phased_sweep([1, 2])
+    sizes = _engine_cache_sizes()
+    second = _phased_sweep([3, 4])
+    assert _engine_cache_sizes() == sizes
+    assert first.exec_time.shape == second.exec_time.shape == (3, 2, 3, 2)
+
+
+def test_sweep_grid_span_makes_no_device_call(monkeypatch):
+    """From the sweep's entry to the executor's hand-off nothing moves
+    between host and device; the engine's inputs, the shared scalars
+    included, reach the executor as host float32."""
+    _phased_sweep([5, 6])  # warm: engine built, progress_max kept
+
+    class Handoff(Exception):
+        pass
+
+    seen = {}
+
+    def run_grid(fn, batched, shared, n_runs, **kw):
+        seen.update(batched=batched, shared=shared, n_runs=n_runs)
+        raise Handoff
+
+    monkeypatch.setattr(executor, "run_grid", run_grid)
+    with jax.transfer_guard("disallow"), pytest.raises(Handoff):
+        _phased_sweep([7, 8])
+    assert seen["n_runs"] == 3 * 2 * 3 * 2
+    for leaf in jax.tree_util.tree_leaves((seen["batched"],
+                                           seen["shared"])):
+        assert not isinstance(leaf, jax.Array)
+    assert [type(x) for x in seen["shared"][:4]] == [np.float32] * 4
+    assert isinstance(seen["shared"][4], np.ndarray)  # the guard vector
