@@ -16,6 +16,7 @@ from repro.core.controller import PIGains
 from repro.core.hierarchy import FleetConfig, simulate_fleet
 from repro.core.nrm import NRM, SimulatedPowerActuator
 from repro.core.plant import PROFILES
+from repro.core.policies import PIPolicy
 from repro.core.sim import simulate_closed_loop, sweep
 
 
@@ -67,7 +68,8 @@ def run(quick: bool = True):
     cfgs = [RLSConfig(lam=l) for l in lams]
     t0 = time.time()
     res = sweep(profs, eps, seeds, total_work=1200.0, max_time=1024.0,
-                adaptive=cfgs, collect_traces=False)
+                policies=[PIPolicy(adaptive=c) for c in cfgs],
+                collect_traces=False)
     grid_s = time.time() - t0
     n_runs = int(np.asarray(res.exec_time).size)
     # mean completion time per lambda, pooled over the other axes

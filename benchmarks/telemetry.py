@@ -13,11 +13,11 @@ Entries (each with first-call and warm wall time plus runs/sec):
   period (heartbeat ingest + Eq. 1 aggregation + the vmapped control
   tick) at 10k mixed-policy tenants; runs/sec is tenant-ticks/sec.
 * ``sweep_throughput`` — the headline metric: warm runs/sec of one
-  summary-mode PI grid through each execution layout (one-shot scan,
-  chunked+donated scan, typed-PI scan, the scan sharded over every local
-  device when there are several, and the Pallas closed-loop kernel —
-  in interpret mode on a reduced grid off the chip). ``improvement`` is
-  best-alternative vs one-shot.
+  summary-mode PI grid through each execution layout (unchunked scan,
+  chunked+donated scan, the scan sharded over every local device when
+  there are several, and the Pallas closed-loop kernel — in interpret
+  mode on a reduced grid off the chip). ``improvement`` is
+  best-alternative vs unchunked (``scan_oneshot``: one chunk).
 
 "cold" is the first in-process call: with a warm persistent XLA cache it
 measures trace + cache load, not a from-scratch compile.
@@ -98,6 +98,7 @@ def collect(quick: bool = True) -> dict:
     from repro.core.hierarchy import FleetConfig, simulate_fleet
     from repro.core.adaptive import RLSConfig
     from repro.core.plant import PROFILES
+    from repro.core.policies import PIPolicy
     from repro.core.sim import sweep
 
     entries = {}
@@ -109,13 +110,14 @@ def collect(quick: bool = True) -> dict:
                       collect_traces=False).exec_time,
         2 * len(eps) * reps)
 
-    cfgs = [RLSConfig(lam=l) for l in (0.97, 0.99, 0.995, 0.999)]
+    pls = [PIPolicy(adaptive=RLSConfig(lam=l))
+           for l in (0.97, 0.99, 0.995, 0.999)]
     seeds = 25 if quick else 250
     entries["adaptive_grid"] = _timed_entry(
         lambda: sweep("gros", (0.05, 0.1, 0.2), range(seeds),
-                      total_work=1200.0, max_time=1024.0, adaptive=cfgs,
+                      total_work=1200.0, max_time=1024.0, policies=pls,
                       collect_traces=False).exec_time,
-        3 * len(cfgs) * seeds)
+        3 * len(pls) * seeds)
 
     for n in (64, 1024):
         prof = PROFILES["dahu"]
@@ -149,10 +151,10 @@ def _sweep_throughput(quick: bool = True) -> dict:
     """Warm runs/sec of ONE summary-mode PI grid through every execution
     layout (`repro.core.sim.sweep` backends / `repro.core.executor`).
     The grid is identical across layouts, so the ratios are honest; the
-    recorded ``improvement`` is best-alternative vs the one-shot scan
-    engine. Off the chip the Pallas kernel rides a reduced grid — the
-    interpreter executes the kernel body op by op, so its number is a
-    correctness-path record, not a horse race."""
+    recorded ``improvement`` is best-alternative vs the unchunked scan
+    (one chunk of every run). Off the chip the Pallas kernel rides a
+    reduced grid — the interpreter executes the kernel body op by op, so
+    its number is a correctness-path record, not a horse race."""
     import jax
 
     from repro.core.sim import sweep
@@ -179,7 +181,6 @@ def _sweep_throughput(quick: bool = True) -> dict:
     backends = {
         "scan_oneshot": timed(scan, n_runs),
         "scan_chunked": timed({**scan, "chunk_size": chunk}, n_runs),
-        "scan_typed_pi": timed({**scan, "typed_pi": True}, n_runs),
     }
     # sharded: ONE chunk split across every local device — chunking pays
     # its dispatch cost only when it buys memory or parallelism, so the

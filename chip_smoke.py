@@ -112,9 +112,10 @@ def _kernel_runs(path: str) -> float:
 
 
 def _scan_compiles() -> tuple:
-    from repro.core import sim
+    from repro.core import executor, sim
     return (sim._flat_core.cache_info().misses,
-            sim._jit_sweep.cache_info().misses)
+            sum(v._cache_size() for v in executor._COMPILED.values()
+                if hasattr(v, "_cache_size")))
 
 
 def _noise_bytes(n_runs: int) -> int:

@@ -1,9 +1,10 @@
 """Chunked, sharded, donation-aware sweep execution (the scale layer).
 
-The scan engine (`repro.core.sim`) compiles one function per grid and
-runs it in one shot: O(grid) device memory in summary mode, O(grid * T)
-in trace mode, one device. This module is the execution layer between a
-grid and the hardware:
+The scan engine (`repro.core.sim`) vmaps one run over a grid's per-run
+rows. In one call that needs O(grid) device memory in summary mode,
+O(grid * T) in trace mode, on one device. This module is the execution
+layer between a grid and the hardware; every `sim.sweep` grid runs
+through it, an unchunked one as a single chunk of all runs:
 
 * **Chunking** — an arbitrarily large flat run list is cut into
   fixed-size tiles (the last tile padded, pad rows discarded), so a

@@ -18,10 +18,11 @@ Entry points:
 * `simulate_closed_loop(profile, ...)` — one run; trimmed numpy traces
   compatible with the old `NRM.run_simulated` return value. Pass
   `adaptive=RLSConfig(...)` to run RLS gain scheduling inside the scan.
-* `sweep(profiles, epsilons, seeds, ...)` — vmapped profiles x epsilons
-  [x rls-configs] x seeds grid in one compiled call; the substrate for
-  Fig. 6/7, paper-scale (30-rep, full eps-grid) sweeps and adaptive
-  hyperparameter grids in CI-feasible time.
+* `sweep(profiles, epsilons, seeds, ...)` — profiles x epsilons
+  [x policies] x seeds grid, flattened to per-run rows and run through
+  `repro.core.executor` (one chunk unless ``chunk_size=`` cuts it); the
+  substrate for Fig. 6/7, paper-scale (30-rep, full eps-grid) sweeps and
+  adaptive hyperparameter grids in CI-feasible time.
 * `engine_step(...)` — the fused single-period step, reused by
   `repro.core.hierarchy` (vmapped over fleet nodes) so fleet runs share
   this engine's compiled dynamics instead of duplicating them.
@@ -60,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import executor
 from repro.core import faults as flt
 from repro.core import plane
 from repro.obs import events as evt
@@ -67,7 +69,7 @@ from repro.obs import trace as obs_trace
 from repro.core import policies as pol
 from repro.core.adaptive import (RLSConfig, RLSState, rls_init, rls_pack,
                                  rls_unpack, rls_values)
-from repro.core.controller import PIGains, PIState, pi_init, pi_step
+from repro.core.controller import PIGains, PIState
 from repro.core.plant import (PROFILE_FIELDS, PROFILES, PlantProfile,
                               PlantState, pcap_linearize, plant_init,
                               plant_step, simulate)
@@ -75,7 +77,7 @@ from repro.core.policies.pi import (PI_RLS_HI, PI_RLS_LO, PIPolicy,
                                     pi_pack)
 from repro.core.workloads.detect import (DET_N_DETECT, DET_STATE_DIM,
                                          DetectorConfig, detect_init,
-                                         detect_step, detector_values)
+                                         detector_values)
 from repro.core.workloads.schedule import (PhaseSchedule, ScheduleValues,
                                            active_profile, chain_rows)
 
@@ -242,8 +244,8 @@ _PI_RLS_LO, _PI_RLS_HI = PI_RLS_LO, PI_RLS_HI
 
 def _default_init(profile: PlantProfile, gains: PIGains,
                   policy=("pi",), policy_vals=None, schedule=None,
-                  det_vals=None, typed_pi: bool = False,
-                  faults=None, guard=None, n_events: int = 0) -> _Carry:
+                  det_vals=None, faults=None, guard=None,
+                  n_events: int = 0) -> _Carry:
     if policy_vals is None:
         policy_vals = jnp.zeros((pol.POLICY_PARAM_DIM,), jnp.float32)
     # a scheduled run starts in its phase-0 plant (the base profile only
@@ -252,8 +254,7 @@ def _default_init(profile: PlantProfile, gains: PIGains,
                   else _unpack_profile(active_profile(schedule,
                                                       jnp.float32(0.0))[0]))
     return _Carry(plant=plant_init(plant_prof),
-                  pol=(pi_init(gains) if typed_pi
-                       else pol.branch_init(policy)(policy_vals, gains)),
+                  pol=pol.branch_init(policy)(policy_vals, gains),
                   pcap=jnp.float32(profile.pcap_max),
                   anchor_gap=jnp.float32(0.0),
                   has_anchor=jnp.array(False),
@@ -319,8 +320,7 @@ def resume_init(plant: PlantState, pi: PIState, pcap,
 def engine_step(profile: PlantProfile, gains: PIGains, c: _Carry,
                 total_work, max_time, dt, key, *, policy=("pi",),
                 policy_vals=None, cap_limit=None, summary_from=0.0,
-                schedule=None, detector=None, typed_pi: bool = False,
-                faults=None, guard=None):
+                schedule=None, detector=None, faults=None, guard=None):
     """One fused control period: plant (Eq. 3) -> heartbeat median
     (Eq. 1) -> power-policy command (Eq. 4 PI by default), with
     early-exit-by-mask freezing and online summary reduction.
@@ -348,12 +348,6 @@ def engine_step(profile: PlantProfile, gains: PIGains, c: _Carry,
     to None, which leaves the static-profile graph byte-identical to the
     pre-phases engine.
 
-    ``typed_pi`` is the single-branch ``("pi",)`` fast path: the carried
-    policy state is a typed `PIState` (two scalars) instead of the
-    packed (POLICY_STATE_DIM,) vector, skipping the pack/unpack data
-    movement every period. Same float ops in the same order, so
-    trajectories are bit-for-bit those of the packed path (tested).
-
     ``faults`` (traced `repro.core.faults.FaultValues`, or None) scripts
     telemetry/actuator failures: heartbeat dropout/staleness, meter
     freeze/bias/spike, stuck/quantized/delayed caps and tenant crashes.
@@ -369,16 +363,6 @@ def engine_step(profile: PlantProfile, gains: PIGains, c: _Carry,
 
     Returns (new_carry, out) where out holds this period's trace row.
     """
-    if typed_pi and tuple(pol.as_branches(policy)) != ("pi",):
-        raise ValueError("typed_pi is the single-branch ('pi',) fast "
-                         f"path; got branches {pol.as_branches(policy)}")
-    if typed_pi and (faults is not None or guard is not None):
-        raise ValueError("typed_pi is the guard-free fixed-gain PI fast "
-                         "path; faults=/guard= need the packed engine")
-    if typed_pi and c.events is not None:
-        raise ValueError("typed_pi is the recorder-free fixed-gain PI "
-                         "fast path; event recording needs the packed "
-                         "engine")
     if policy_vals is None:
         policy_vals = jnp.zeros((pol.POLICY_PARAM_DIM,), jnp.float32)
     if schedule is None:
@@ -454,36 +438,24 @@ def engine_step(profile: PlantProfile, gains: PIGains, c: _Carry,
         prog_obs, power_obs = progress, meas["power"]
         fstate_n = c.fstate
 
-    if typed_pi:
-        # single-branch PI fast path: detector still runs (fixed-gain
-        # PI's on_change is the identity, so no dispatch is needed)
-        if detector is None:
-            det_s, change = c.det, jnp.float32(0.0)
-        else:
-            det_s, detected = detect_step(detector, c.det, progress,
-                                          gains.linearize(c.pcap), dt)
-            change = detected.astype(jnp.float32)
-        pol_s, pcap = pi_step(gains, c.pol, progress, dt)
+    # the control plane's single control-law code path: detector
+    # residual against the design model's replay of the APPLIED cap,
+    # alarm -> the policy's on_change reaction, then the policy step
+    # (repro.core.plane owns this section; the NRM runtime and the
+    # multi-tenant service tick call the same function). The controller
+    # sees the OBSERVED telemetry — identical to the measured values
+    # when faults is None.
+    if guard is None:
+        pol_s, det_s, pcap, change = plane.plane_step(
+            gains, policy, policy_vals, c.pol, c.pcap, prog_obs,
+            power_obs, dt, det_vals=detector, det_state=c.det)
         guard_s, gmode = c.guard, None
     else:
-        # the control plane's single control-law code path: detector
-        # residual against the design model's replay of the APPLIED
-        # cap, alarm -> the policy's on_change reaction, then the
-        # policy step (repro.core.plane owns this section; the NRM
-        # runtime and the multi-tenant service tick call the same
-        # function). The controller sees the OBSERVED telemetry —
-        # identical to the measured values when faults is None.
-        if guard is None:
-            pol_s, det_s, pcap, change = plane.plane_step(
-                gains, policy, policy_vals, c.pol, c.pcap, prog_obs,
-                power_obs, dt, det_vals=detector, det_state=c.det)
-            guard_s, gmode = c.guard, None
-        else:
-            (pol_s, det_s, pcap, change, guard_s,
-             gmode) = plane.plane_step(
-                gains, policy, policy_vals, c.pol, c.pcap, prog_obs,
-                power_obs, dt, det_vals=detector, det_state=c.det,
-                guard_vals=guard, guard_state=c.guard)
+        (pol_s, det_s, pcap, change, guard_s,
+         gmode) = plane.plane_step(
+            gains, policy, policy_vals, c.pol, c.pcap, prog_obs,
+            power_obs, dt, det_vals=detector, det_state=c.det,
+            guard_vals=guard, guard_state=c.guard)
     if cap_limit is not None:
         pcap = jnp.minimum(pcap, cap_limit)
 
@@ -535,8 +507,7 @@ def engine_step(profile: PlantProfile, gains: PIGains, c: _Carry,
         out["phase"] = jnp.where(c.done, -1, phase_idx)
     if detector is not None:
         out["phase_change"] = change
-    if not typed_pi:
-        out.update(pol.branch_extras(policy)(pol_s))
+    out.update(pol.branch_extras(policy)(pol_s))
 
     # flight recorder: edge-triggered appends into the carried ring.
     # Every append is gated on the live mask (and the whole block on the
@@ -594,8 +565,7 @@ def engine_step(profile: PlantProfile, gains: PIGains, c: _Carry,
 
 
 def _scan_core(max_steps: int, collect: bool = True,
-               branches=("pi",), typed_pi: bool = False,
-               n_events: int = 0):
+               branches=("pi",), n_events: int = 0):
     """Pure closed-loop run: (profile_vals, gains_vals, policy_vals,
     sched, det_vals, fvals, gvals, init|None, total_work, max_time, dt,
     summary_from, key) -> (traces|None, final_carry). The policy branch
@@ -604,11 +574,9 @@ def _scan_core(max_steps: int, collect: bool = True,
     None (static plant, no detector, no faults, no guard — the
     pre-existing graph, byte-identical) or traced `ScheduleValues` /
     detector / `FaultValues` / guard parameter vectors; jit separates
-    the variants by pytree structure. ``typed_pi`` switches the carried
-    policy state to a typed `PIState` (single-branch ('pi',) fast path;
-    an ``init`` carry must then also hold a typed pol). ``n_events`` > 0
-    arms the flight recorder with that many ring slots (static: the ring
-    shape keys the jit cache; 0 keeps the recorder-free carry)."""
+    the variants by pytree structure. ``n_events`` > 0 arms the flight
+    recorder with that many ring slots (static: the ring shape keys the
+    jit cache; 0 keeps the recorder-free carry)."""
 
     def run(profile_vals, gains_vals, policy_vals, sched, det_vals,
             fvals, gvals, init: Optional[_Carry], total_work, max_time,
@@ -616,8 +584,7 @@ def _scan_core(max_steps: int, collect: bool = True,
         profile = _unpack_profile(profile_vals)
         gains = _unpack_gains(gains_vals)
         carry0 = (_default_init(profile, gains, branches, policy_vals,
-                                sched, det_vals, typed_pi, fvals, gvals,
-                                n_events)
+                                sched, det_vals, fvals, gvals, n_events)
                   if init is None else init)
 
         def body(c: _Carry, k):
@@ -626,8 +593,7 @@ def _scan_core(max_steps: int, collect: bool = True,
                                   policy_vals=policy_vals,
                                   summary_from=summary_from,
                                   schedule=sched, detector=det_vals,
-                                  typed_pi=typed_pi, faults=fvals,
-                                  guard=gvals)
+                                  faults=fvals, guard=gvals)
             return c2, (out if collect else None)
 
         keys = jax.random.split(key, max_steps)
@@ -648,74 +614,21 @@ def _jit_run(max_steps: int, collect: bool = True, branches=("pi",),
                               n_events=n_events))
 
 
-@functools.lru_cache(maxsize=None)
-def _jit_sweep_cached(max_steps: int, branches, collect: bool,
-                      scheduled: bool, detected: bool,
-                      typed_pi: bool = False, det_grid: bool = False,
-                      fault_grid: bool = False, n_events: int = 0):
-    run = _scan_core(max_steps, collect, branches, typed_pi, n_events)
-    f = lambda pv, gv, av, sv, dv, fv, gvl, tw, mt, dt, sf, key: run(
-        pv, gv, av, sv, dv, fv, gvl, None, tw, mt, dt, sf, key)
-    sched_ax = 0 if scheduled else None
-    det_ax = 0 if detected else None
-    f = jax.vmap(f, in_axes=(None,) * 11 + (0,))                 # seeds
-    if fault_grid:
-        # fault-scenario axis: fv rows are per-FaultSchedule (plant-
-        # independent, so no profile coupling like sched/det)
-        f = jax.vmap(f, in_axes=(None,) * 5 + (0,) + (None,) * 6)
-    if det_grid:
-        # detector hyperparameter axis (threshold/min_gap/... grids),
-        # vmapped like the RLS-config axis: dv rows are per-config
-        f = jax.vmap(f, in_axes=(None, None, None, None, 0)
-                     + (None,) * 7)
-    if scheduled:
-        f = jax.vmap(f, in_axes=(None, None, None, 0) + (None,) * 8)
-    f = jax.vmap(f, in_axes=(None, None, 0) + (None,) * 9)       # policies
-    f = jax.vmap(f, in_axes=(None, 0, None) + (None,) * 9)       # eps
-    f = jax.vmap(f, in_axes=(0, 0, 0, sched_ax, det_ax, None, None)
-                 + (None,) * 5)                                  # profs
-    return jax.jit(f)
-
-
-def _jit_sweep(max_steps: int, branches=("pi",), collect: bool = True,
-               scheduled: bool = False, detected: bool = False,
-               typed_pi: bool = False, det_grid: bool = False,
-               fault_grid: bool = False, n_events: int = 0):
-    """Vmapped grid engine. Axis nest (outer->inner): profiles, eps,
-    policies, [workloads], [detectors], [faults], seeds; the workload/
-    detector/fault axes exist only when ``scheduled`` / ``det_grid`` /
-    ``fault_grid`` (so sweeps without them keep their exact
-    pre-existing shapes and executables). Schedule leaves are
-    (P, W, ...) — resolved per profile; detector values are per-profile
-    (P, DET_PARAM_DIM), or (P, D, DET_PARAM_DIM) with a detector-config
-    grid; fault leaves are (F, MAX_FAULT_ROWS) stacked FaultValues (a
-    SINGLE FaultSchedule rides unstacked with no axis). A plain wrapper
-    over the lru cache so defaulted and explicit calls share one cache
-    key."""
-    return _jit_sweep_cached(max_steps, tuple(branches), bool(collect),
-                             bool(scheduled), bool(detected),
-                             bool(typed_pi), bool(det_grid),
-                             bool(fault_grid), int(n_events))
-
-
-_jit_sweep.cache_info = _jit_sweep_cached.cache_info
-
-
-# ---- executor backends (chunked / sharded / donated grids) ----------------
+# ---- grid engines, run by the executor (chunked / sharded / donated) ------
 
 @functools.lru_cache(maxsize=None)
 def _flat_core(max_steps: int, branches, collect: bool, scheduled: bool,
-               detected: bool, typed_pi: bool = False,
-               guarded: bool = False, n_events: int = 0):
-    """Flat-grid engine for the executor: ONE vmap over per-run rows
-    (a dict of (N, ...) leaves) instead of the one-shot nest. Every
-    run's parameters and key ride in its own row, so ANY slice of the
-    flattened grid computes identical per-run results — which is what
-    makes chunked/sharded == one-shot exact. Fault rows (when present)
-    ride the batched dict like sched/det; the guard parameter vector is
-    grid-wide, so it rides the shared argument tail (``guarded``
-    selects the variant)."""
-    run = _scan_core(max_steps, collect, branches, typed_pi, n_events)
+               detected: bool, guarded: bool, n_events: int):
+    """The scan engine of every `sweep` grid: ONE vmap over per-run rows
+    (a dict of (N, ...) leaves: plant, gains, policy values, key, and
+    the schedule / detector / fault rows when present). Every run's
+    parameters and key ride in its own row, so any slice of the
+    flattened grid computes the same per-run model. The guard parameter
+    vector is grid-wide, so it rides the shared argument tail
+    (``guarded`` selects the variant). ``scheduled`` and ``detected``
+    only key the cache: the rows' pytree structure already separates
+    those traces."""
+    run = _scan_core(max_steps, collect, branches, n_events)
 
     def sweep_scan(batched, total_work, max_time, dt, summary_from, *rest):
         gvl = rest[0] if guarded else None
@@ -922,10 +835,9 @@ class SweepResult:
     x seeds.
 
     Trace arrays have shape (..., T) where ... is (P, E, S) — or
-    (P, E, A, S) for policy/adaptive grids, (P, E, A, W, S) with a
-    workload axis — with the P (and A, W) axes squeezed away when a
-    single profile (single Policy/RLSConfig, single PhaseSchedule) was
-    passed. Frozen
+    (P, E, A, S) for policy grids, (P, E, A, W, S) with a workload
+    axis — with the P (and A, W) axes squeezed away when a single
+    profile (single Policy, single PhaseSchedule) was passed. Frozen
     (post-completion) steps carry `valid == False`. In summary mode
     (`collect_traces=False`) `traces` is None and only `summary` (plus
     the scalar reductions) is materialized: O(grid) memory, not
@@ -1170,7 +1082,7 @@ def simulate_closed_loop(profile: Union[str, PlantProfile],
 class _GridValues(NamedTuple):
     """A sweep's parameter tables, host float32 throughout: the engines
     trace them, and they cross to the device with the engine's inputs
-    (the executor's per-chunk transfer, or the one-shot jit call)."""
+    (the executor's per-chunk transfer)."""
     pv: np.ndarray                  # (P, len(PROFILE_FIELDS))
     gv: np.ndarray                  # (P, E, GAIN_DIM)
     av: np.ndarray                  # (P, A, POLICY_PARAM_DIM)
@@ -1198,8 +1110,8 @@ def _grid_values(profs: Sequence[PlantProfile], eps: Sequence[float],
         np.stack([gains_values(PIGains.from_model(p, e, tau_obj))
                   for e in eps]) for p in profs])
     # policy values grid (P, A, PARAM_DIM), built at the eps[0] design
-    # point per profile (cf. the adaptive grid: kl_ref/tau_obj depend
-    # only on the profile)
+    # point per profile (the PI-RLS values: kl_ref/tau_obj depend only
+    # on the profile)
     av = np.stack([
         np.stack([pol.policy_values(
             p_, p, PIGains.from_model(p, eps[0], tau_obj), kind=k)
@@ -1233,7 +1145,7 @@ def _grid_values(profs: Sequence[PlantProfile], eps: Sequence[float],
                 raise ValueError("detector= needs at least one "
                                  "DetectorConfig")
             # detector hyperparameter grid (P, D, DET_PARAM_DIM): a new D
-            # axis between [workloads] and seeds, like the adaptive= grid
+            # axis between [workloads] and seeds, like the policy axis
             dv = np.stack([np.stack([detector_values(d, p)
                                      for d in det_cfgs])
                            for p in profs])
@@ -1278,8 +1190,6 @@ def _sweep_run(profiles: Union[str, PlantProfile,
                max_time: float = 3600.0,
                dt: float = 1.0,
                tau_obj: float = 10.0,
-               adaptive: Union[None, RLSConfig,
-                               Sequence[RLSConfig]] = None,
                policies: Union[None, pol.Policy,
                                Sequence[pol.Policy]] = None,
                collect_traces: bool = True,
@@ -1295,17 +1205,16 @@ def _sweep_run(profiles: Union[str, PlantProfile,
                backend: str = "auto",
                chunk_size: Optional[int] = None,
                devices=None,
-               typed_pi: bool = False,
                consume=None,
                state=None,
                stop_after: Optional[int] = None,
                durable=None,
                campaign=None):
     """`_sweep_impl`'s work: normalizes the grid (``sweep/grid``, with
-    ``sweep/keys``, ``sweep/scenario`` and ``sweep/rows``), then runs it
-    one-shot (the legacy exact path) or through `repro.core.executor`,
-    counts a scan-engine result's runs in ``closed_loop_runs_total
-    {path="scan"}``, and assembles the result (``sweep/summary``).
+    ``sweep/keys``, ``sweep/scenario`` and ``sweep/rows``), runs its
+    per-run rows through `repro.core.executor`, counts a scan-engine
+    result's runs in ``closed_loop_runs_total{path="scan"}``, and
+    assembles the result (``sweep/summary``).
     Returns (SweepResult | None, ExecState | None)."""
     tracer = obs_trace.get_tracer()
     with tracer.span("sweep/grid"):
@@ -1316,19 +1225,8 @@ def _sweep_run(profiles: Union[str, PlantProfile,
         if not (profs and eps and seeds):
             raise ValueError("sweep needs at least one profile, epsilon and "
                              "seed")
-        if adaptive is not None and policies is not None:
-            raise ValueError("pass policies= or adaptive=, not both "
-                             "(adaptive= is sugar for PIPolicy(adaptive=...))")
         if policies is None:
-            if adaptive is None:
-                pls, squeeze_pol = [PIPolicy()], True
-            else:
-                single_cfg = isinstance(adaptive, RLSConfig)
-                cfgs = [adaptive] if single_cfg else list(adaptive)
-                if not cfgs:
-                    raise ValueError("adaptive= needs at least one RLSConfig")
-                pls = [PIPolicy(adaptive=c) for c in cfgs]
-                squeeze_pol = single_cfg
+            pls, squeeze_pol = [PIPolicy()], True
         else:
             squeeze_pol = isinstance(policies, pol.Policy)
             pls = [policies] if squeeze_pol else list(policies)
@@ -1341,18 +1239,7 @@ def _sweep_run(profiles: Union[str, PlantProfile,
          fault_grid) = _grid_values(profs, eps, pls, kinds, tau_obj,
                                     workloads=workloads, detector=detector,
                                     faults=faults, guard=guard)
-        if typed_pi and branches != ("pi",):
-            raise ValueError("typed_pi= is the single-branch fixed-gain PI "
-                             f"fast path; this grid dispatches {branches}")
-        if typed_pi and (fv is not None or gvl is not None):
-            raise ValueError("typed_pi= is the guard-free fixed-gain PI "
-                             "fast path; faults=/guard= need the packed "
-                             "engine")
         n_events = _resolve_n_events(record_events)
-        if typed_pi and n_events:
-            raise ValueError("typed_pi= is the recorder-free fixed-gain PI "
-                             "fast path; record_events= needs the packed "
-                             "engine")
         if backend not in ("scan", "pallas", "auto"):
             raise ValueError(f"unknown backend {backend!r}; choose "
                              "'scan', 'pallas' or 'auto'")
@@ -1363,13 +1250,9 @@ def _sweep_run(profiles: Union[str, PlantProfile,
         if backend == "auto":
             # capability dispatch: the mega-kernel covers the flagship
             # fixed-gain PI path and pays off where it lowers natively; the
-            # interpreted kernel is for correctness work, not speed.
-            # typed_pi= names a carry of the scan engine, so it keeps the scan
-            backend = ("pallas" if pallas_ok and not typed_pi
+            # interpreted kernel is for correctness work, not speed
+            backend = ("pallas" if pallas_ok
                        and jax.default_backend() == "tpu" else "scan")
-        elif backend == "pallas" and typed_pi:
-            raise ValueError("typed_pi= is a carry of the scan engine; the "
-                             "Pallas kernel has its own — use backend='scan'")
         elif backend == "pallas" and not pallas_ok:
             raise ValueError(
                 "backend='pallas' covers the fixed-gain PI path only "
@@ -1388,99 +1271,82 @@ def _sweep_run(profiles: Union[str, PlantProfile,
              else 1)
         shape7 = (P, E, A, W, D, F, S)
         n_runs = int(np.prod(shape7))
-        use_exec = (backend != "scan" or chunk_size is not None
-                    or devices is not None or consume is not None
-                    or state is not None or stop_after is not None
-                    or durable is not None)
-        if use_exec:
-            with tracer.span("sweep/rows"):
-                # flatten the grid to per-run rows (grid-nest order, so the
-                # merged leading axis reshapes straight back to
-                # (P,E,A,[W],[D],[F],S))
-                (ip, ie, ia, iw, idet, ifl,
-                 is_) = np.indices(shape7).reshape(7, n_runs)
-                batched = {"prof": pv[ip], "gains": gv[ip, ie],
-                           "pvals": av[ip, ia], "key": keys[is_]}
-                if sv is not None:
-                    batched["sched"] = jax.tree_util.tree_map(
-                        lambda x: x[ip, iw], sv)
-                if dv is not None:
-                    batched["det"] = dv[ip, idet] if det_grid else dv[ip]
-                if fv is not None:
-                    # fault rows always ride the per-run rows here (a single
-                    # schedule broadcasts), so chunk slicing stays uniform
-                    batched["faults"] = jax.tree_util.tree_map(
-                        lambda x: (x[ifl] if fault_grid
-                                   else np.broadcast_to(
-                                       x, (n_runs,) + np.shape(x)).copy()),
-                        fv)
-            if backend == "pallas":
-                # the op jits itself; with devices= the executor places one
-                # slice of every chunk on each device
-                fn = _flat_core_pallas(collect_traces)
-                shared = (float(total_work), float(max_time), float(dt),
-                          float(summary_warmup))
-                wrap = "none"
-            else:
-                fn = _flat_core(max_steps, branches, collect_traces,
-                                sv is not None, dv is not None, typed_pi,
-                                gvl is not None, n_events)
-                shared = (np.float32(total_work), np.float32(max_time),
-                          np.float32(dt), np.float32(summary_warmup))
-                if gvl is not None:
-                    shared = shared + (gvl,)
-                wrap = "jit"
-    exec_state = None
-    if not use_exec:
-        traces, final = _jit_sweep(max_steps, branches, collect_traces,
-                                   sv is not None, dv is not None,
-                                   typed_pi, det_grid, fault_grid,
-                                   n_events)(
-            pv, gv, av, sv, dv, fv, gvl, jnp.float32(total_work),
-            jnp.float32(max_time), jnp.float32(dt),
-            jnp.float32(summary_warmup), keys)
-    else:
-        from repro.core import executor
-        if durable is not None:
-            # journaled, retried, quarantine-capable campaign path —
-            # same grid, same per-run rows, so the merged result is
-            # bit-for-bit the plain run_grid one
-            from repro.core import supervisor
-            merged, report = supervisor.run_durable(
-                fn, batched, shared, n_runs, dir=durable,
-                chunk_size=chunk_size, devices=devices, wrap=wrap,
-                consume=consume, config=campaign)
-            exec_state = report.state
-            if report.dead:
-                # dead-lettered chunks leave their rows unfilled: a
-                # result assembled from them would be garbage
-                raise RuntimeError(
-                    f"campaign {durable}: chunks {report.dead} were "
-                    "dead-lettered, so their runs were never computed "
-                    "(the journal there records each error)")
+        with tracer.span("sweep/rows"):
+            # flatten the grid to per-run rows (grid-nest order, so the
+            # merged leading axis reshapes straight back to
+            # (P,E,A,[W],[D],[F],S))
+            (ip, ie, ia, iw, idet, ifl,
+             is_) = np.indices(shape7).reshape(7, n_runs)
+            batched = {"prof": pv[ip], "gains": gv[ip, ie],
+                       "pvals": av[ip, ia], "key": keys[is_]}
+            if sv is not None:
+                batched["sched"] = jax.tree_util.tree_map(
+                    lambda x: x[ip, iw], sv)
+            if dv is not None:
+                batched["det"] = dv[ip, idet] if det_grid else dv[ip]
+            if fv is not None:
+                # fault rows always ride the per-run rows here (a single
+                # schedule broadcasts), so chunk slicing stays uniform
+                batched["faults"] = jax.tree_util.tree_map(
+                    lambda x: (x[ifl] if fault_grid
+                               else np.broadcast_to(
+                                   x, (n_runs,) + np.shape(x)).copy()),
+                    fv)
+        if backend == "pallas":
+            # the op jits itself; with devices= the executor places one
+            # slice of every chunk on each device
+            fn = _flat_core_pallas(collect_traces)
+            shared = (float(total_work), float(max_time), float(dt),
+                      float(summary_warmup))
+            wrap = "none"
         else:
-            merged, exec_state = executor.run_grid(
-                fn, batched, shared, n_runs, chunk_size=chunk_size,
-                devices=devices, wrap=wrap, consume=consume, state=state,
-                stop_after=stop_after)
-        if merged is None:  # consume hook ran, or stop_after cut short
-            return None, exec_state
-        traces, final = merged
+            fn = _flat_core(max_steps, branches, collect_traces,
+                            sv is not None, dv is not None,
+                            gvl is not None, n_events)
+            shared = (np.float32(total_work), np.float32(max_time),
+                      np.float32(dt), np.float32(summary_warmup))
+            if gvl is not None:
+                shared = shared + (gvl,)
+            wrap = "jit"
+    if durable is not None:
+        # journaled, retried, quarantine-capable campaign path — same
+        # grid, same per-run rows, so the merged result is bit-for-bit
+        # the plain run_grid one
+        from repro.core import supervisor
+        merged, report = supervisor.run_durable(
+            fn, batched, shared, n_runs, dir=durable,
+            chunk_size=chunk_size, devices=devices, wrap=wrap,
+            consume=consume, config=campaign)
+        exec_state = report.state
+        if report.dead:
+            # dead-lettered chunks leave their rows unfilled: a result
+            # assembled from them would be garbage
+            raise RuntimeError(
+                f"campaign {durable}: chunks {report.dead} were "
+                "dead-lettered, so their runs were never computed "
+                "(the journal there records each error)")
+    else:
+        merged, exec_state = executor.run_grid(
+            fn, batched, shared, n_runs, chunk_size=chunk_size,
+            devices=devices, wrap=wrap, consume=consume, state=state,
+            stop_after=stop_after)
+    if merged is None:  # consume hook ran, or stop_after cut short
+        return None, exec_state
+    traces, final = merged
     if backend == "scan":
         # the kernel op counts its own runs (native, interpret, ref)
         from repro.kernels.closed_loop.ops import runs_counter
         runs_counter().inc(n_runs, path="scan")
     with tracer.span("sweep/summary"):
-        if use_exec:
-            if backend == "pallas":
-                final = _carry_from_kernel_final(final)
-            out_shape = ((P, E, A) + ((W,) if sv is not None else ())
-                         + ((D,) if det_grid else ())
-                         + ((F,) if fault_grid else ()) + (S,))
-            reshape = lambda x: x.reshape(out_shape + x.shape[1:])
-            traces = (None if traces is None
-                      else jax.tree_util.tree_map(reshape, traces))
-            final = jax.tree_util.tree_map(reshape, final)
+        if backend == "pallas":
+            final = _carry_from_kernel_final(final)
+        out_shape = ((P, E, A) + ((W,) if sv is not None else ())
+                     + ((D,) if det_grid else ())
+                     + ((F,) if fault_grid else ()) + (S,))
+        reshape = lambda x: x.reshape(out_shape + x.shape[1:])
+        traces = (None if traces is None
+                  else jax.tree_util.tree_map(reshape, traces))
+        final = jax.tree_util.tree_map(reshape, final)
         edges = {k: np.stack([_hist_edges(p)[k] for p in profs])
                  for k in ("progress_edges", "pcap_edges")}
         summary = _summary_dict(final, edges)
@@ -1516,14 +1382,13 @@ def _sweep_run(profiles: Union[str, PlantProfile,
 
 
 def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,
-          dt=1.0, tau_obj=10.0, adaptive=None, policies=None,
+          dt=1.0, tau_obj=10.0, policies=None,
           collect_traces=True, summary_warmup=0, workloads=None,
           detector=None, faults=None, guard=None,
           record_events=None, *,
           backend: str = "auto",
           chunk_size: Optional[int] = None, devices=None,
-          typed_pi: bool = False, consume=None,
-          durable=None, campaign=None
+          consume=None, durable=None, campaign=None
           ) -> Optional[SweepResult]:
     """Vmapped closed-loop grid: profiles x epsilons [x policies]
     [x workloads] x seeds.
@@ -1537,11 +1402,11 @@ def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,
     `lax.switch`-dispatched engine, one compile per scan-length bucket.
 
     Pass `policies=` a single Policy (axis squeezed) or a sequence
-    (inserts an A axis between epsilons and seeds); `adaptive=` is sugar
-    for ``policies=[PIPolicy(adaptive=cfg) for cfg in ...]`` with the
-    same squeeze semantics (a profile-dependent policy's `values` are
-    built at the epsilon[0] design point — the PI-RLS values only use
-    the epsilon-independent k_i). `collect_traces=False` switches to the
+    (inserts an A axis between epsilons and seeds); an RLS
+    hyperparameter grid is ``policies=[PIPolicy(adaptive=cfg) for cfg
+    in ...]`` (a profile-dependent policy's `values` are built at the
+    epsilon[0] design point — the PI-RLS values only use the
+    epsilon-independent k_i). `collect_traces=False` switches to the
     O(grid)-memory summary mode for very large grids. `summary_warmup`
     excludes each run's first steps (the descent transient) from the
     online summary reductions only.
@@ -1556,8 +1421,8 @@ def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,
     `SweepResult.detections` then carries per-run alarm counts. A
     SEQUENCE of DetectorConfigs sweeps the detector hyperparameters
     (threshold, min_gap, drift, ...) as their own grid axis — a D axis
-    between [workloads] and seeds, vmapped like the RLS-config axis —
-    for threshold/ROC tuning in one compiled call.
+    between [workloads] and seeds — for threshold/ROC tuning in one
+    compiled call.
 
     `faults=` scripts telemetry/actuator failures inside every run
     (`repro.core.faults.FaultSchedule`): a single schedule applies to
@@ -1576,17 +1441,18 @@ def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,
     recorder-off sweeps keep the exact recorder-free executable under
     the same None-leaves contract.
 
-    Execution layer (`repro.core.executor`): with every keyword at its
-    default, a grid the scan engine runs goes ONE-SHOT through the
-    legacy nested-vmap engine — bit-for-bit the pre-executor `sweep`.
-    ``chunk_size=`` cuts the flattened grid into bounded-memory tiles
-    (buffer donation between tiles, streaming merge on host — a 1M-run
-    summary grid no longer has to fit in one vmap); ``devices=`` ("all",
-    an int, or a device
+    Execution layer (`repro.core.executor`): every grid is flattened to
+    per-run rows and run by `executor.run_grid`; with the default
+    ``chunk_size=None`` that is one chunk of all runs. ``chunk_size=``
+    cuts the rows into bounded-memory tiles (buffer donation between
+    tiles, streaming merge on host — a 1M-run summary grid no longer
+    has to fit in one vmap); ``devices=`` ("all", an int, or a device
     list) shards tiles across devices via pmap with a single-device
-    fallback; per-run results are identical in every configuration
-    because each run's parameters and RNG stream ride in its own row.
-    ``backend="pallas"`` dispatches to the fused closed-loop Pallas
+    fallback. Each run's parameters and RNG stream ride in its own row,
+    so every layout computes the same per-run model: counts agree
+    exactly, floats within a few ulp across chunk shapes (two chunk
+    shapes are two XLA executables). ``backend="pallas"`` dispatches to
+    the fused closed-loop Pallas
     mega-kernel (`repro.kernels.closed_loop`; fixed-gain PI, static
     plant, no detector — same model, its own per-run noise stream;
     with ``devices=`` each chunk is split over the devices). The
@@ -1598,11 +1464,6 @@ def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,
     where the scan draws a Poisson, which moves per-(plant, epsilon)
     medians by up to ~2% (ROADMAP D2). Pass ``backend="scan"`` for the
     CPU's exact samples on any platform.
-    ``typed_pi=``
-    switches the single-branch PI scan engine to the typed-PIState carry
-    (bit-for-bit the packed path; kept as a measured fast-path toggle);
-    it keeps ``backend="auto"`` on the scan and is refused with
-    ``backend="pallas"``.
     ``consume=`` streams per-chunk results to a callback ``consume(lo,
     hi, (traces, final))`` instead of accumulating them (the offline-RL
     dataset harvester) — `sweep` then returns None.
@@ -1625,30 +1486,29 @@ def sweep(profiles, epsilons, seeds, total_work, max_time=3600.0,
         supervisor.save_campaign_spec(durable, "sweep", dict(
             profiles=profiles, epsilons=list(epsilons),
             seeds=list(seeds), total_work=total_work, max_time=max_time,
-            dt=dt, tau_obj=tau_obj, adaptive=adaptive, policies=policies,
+            dt=dt, tau_obj=tau_obj, policies=policies,
             collect_traces=collect_traces, summary_warmup=summary_warmup,
             workloads=workloads, detector=detector, faults=faults,
             guard=guard, record_events=record_events, backend=backend,
-            chunk_size=chunk_size, devices=devices, typed_pi=typed_pi,
-            campaign=campaign))
+            chunk_size=chunk_size, devices=devices, campaign=campaign))
     res, _ = _sweep_impl(profiles, epsilons, seeds, total_work,
-                         max_time, dt, tau_obj, adaptive, policies,
+                         max_time, dt, tau_obj, policies,
                          collect_traces, summary_warmup, workloads,
                          detector, faults, guard, record_events,
                          backend=backend,
                          chunk_size=chunk_size, devices=devices,
-                         typed_pi=typed_pi, consume=consume,
-                         durable=durable, campaign=campaign)
+                         consume=consume, durable=durable,
+                         campaign=campaign)
     return res
 
 
 def sweep_resumable(profiles, epsilons, seeds, total_work,
                     max_time=3600.0, dt=1.0, tau_obj=10.0,
-                    adaptive=None, policies=None, collect_traces=True,
+                    policies=None, collect_traces=True,
                     summary_warmup=0, workloads=None, detector=None,
                     faults=None, guard=None, record_events=None, *,
                     backend: str = "auto", chunk_size: int,
-                    devices=None, typed_pi: bool = False, state=None,
+                    devices=None, state=None,
                     stop_after: Optional[int] = None):
     """Chunked sweep that can stop and resume ACROSS chunk boundaries:
     returns (SweepResult | None, `executor.ExecState`). ``stop_after=``
@@ -1657,12 +1517,11 @@ def sweep_resumable(profiles, epsilons, seeds, total_work,
     pickles — back via ``state=`` to continue where the previous call
     (or process) left off. Same grid semantics as `sweep`."""
     return _sweep_impl(profiles, epsilons, seeds, total_work, max_time,
-                       dt, tau_obj, adaptive, policies, collect_traces,
+                       dt, tau_obj, policies, collect_traces,
                        summary_warmup, workloads, detector, faults,
                        guard, record_events, backend=backend,
-                       chunk_size=chunk_size,
-                       devices=devices, typed_pi=typed_pi, state=state,
-                       stop_after=stop_after)
+                       chunk_size=chunk_size, devices=devices,
+                       state=state, stop_after=stop_after)
 
 
 @functools.lru_cache(maxsize=None)

@@ -144,6 +144,8 @@ def test_sweep_pallas_chunked_equals_one_shot():
 
 def test_sweep_pallas_rejects_incapable_grids():
     from repro.core.adaptive import RLSConfig
+    from repro.core.policies import PIPolicy
     with pytest.raises(ValueError, match="pallas"):
         sim.sweep("gros", [0.1], [0], total_work=100.0,
-                  adaptive=RLSConfig(), backend="pallas")
+                  policies=PIPolicy(adaptive=RLSConfig()),
+                  backend="pallas")

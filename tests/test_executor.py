@@ -55,11 +55,13 @@ def test_chunked_adaptive_and_workload_axes():
     """Chunking slices the FLATTENED grid, so multi-axis grids (eps x
     rls-configs x seeds, workload axes) must reassemble exactly."""
     from repro.core.adaptive import RLSConfig
+    from repro.core.policies import PIPolicy
     from repro.core.workloads import Phase, PhaseSchedule
-    cfgs = [RLSConfig(lam=0.99), RLSConfig(lam=0.999)]
-    one = sweep("gros", [0.1, 0.2], range(2), adaptive=cfgs,
+    pls = [PIPolicy(adaptive=RLSConfig(lam=0.99)),
+           PIPolicy(adaptive=RLSConfig(lam=0.999))]
+    one = sweep("gros", [0.1, 0.2], range(2), policies=pls,
                 collect_traces=False, **KW)
-    ch = sweep("gros", [0.1, 0.2], range(2), adaptive=cfgs,
+    ch = sweep("gros", [0.1, 0.2], range(2), policies=pls,
                collect_traces=False, chunk_size=3, **KW)
     np.testing.assert_array_equal(np.asarray(one.exec_time),
                                   np.asarray(ch.exec_time))

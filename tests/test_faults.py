@@ -147,11 +147,11 @@ def test_sweep_noop_fault_axis_bit_identical_to_clean():
                                   np.asarray(faulted.energy[:, 1]))
 
 
-# The one-shot nest (`sim._jit_sweep`) and the executor's flat rows
-# (`sim._flat_core`) are different XLA executables, and XLA does not
-# promise bit equality between two executables: fusion may reorder a
-# float32 sum (ROADMAP D0). Counts stay exact; float fields agree to
-# this many units in the last place.
+# An unchunked grid and its chunks run the same flat-row engine
+# (`sim._flat_core`) at different chunk shapes, which are different XLA
+# executables, and XLA does not promise bit equality between two
+# executables: fusion may reorder a float32 sum. Counts stay exact;
+# float fields agree to this many units in the last place.
 MAX_ULP = 4
 GUARD_FLOATS = [flt.G_LAST_PROGRESS, flt.G_LAST_POWER]
 GUARD_COUNTS = [i for i in range(flt.GUARD_STATE_DIM)

@@ -164,10 +164,6 @@ def test_recorder_neutral_on_sweep_axis_and_chunked():
 
 
 def test_recorder_excluded_from_fast_paths():
-    with pytest.raises(ValueError, match="typed_pi"):
-        sweep("gros", (0.1,), range(2), total_work=500.0,
-              max_time=100.0, collect_traces=False, typed_pi=True,
-              record_events=True)
     with pytest.raises(ValueError, match="record_events"):
         sweep("gros", (0.1,), range(2), total_work=500.0,
               max_time=100.0, collect_traces=False, backend="pallas",

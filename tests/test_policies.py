@@ -21,6 +21,7 @@ from repro.core.plant import PROFILES, plant_init, plant_step
 from repro.core.policies import (DutyCyclePolicy, OfflineRLPolicy, PIPolicy,
                                  build_dataset, fit_offline_rl)
 from repro.core.sim import simulate_closed_loop, sweep
+from test_sim import _engine_cache_sizes
 
 
 # ---------------------------------------------------------------------------
@@ -148,17 +149,17 @@ def test_sweep_policies_pi_equals_legacy_sweep():
                                   np.asarray(b.exec_time[:, 0]))
     np.testing.assert_array_equal(np.asarray(a.traces["pcap"]),
                                   np.asarray(b.traces["pcap"][:, 0]))
-    # adaptive= is sugar for PIPolicy(adaptive=...): same results
+    # a single adaptive PIPolicy is the squeezed A axis of its list
     cfgs = [RLSConfig(lam=0.99), RLSConfig(lam=0.999)]
-    c = sweep("gros", [0.1], range(2), adaptive=cfgs,
+    c = sweep("gros", [0.1], range(2), policies=PIPolicy(adaptive=cfgs[0]),
               collect_traces=False, **kw)
     d = sweep("gros", [0.1], range(2),
               policies=[PIPolicy(adaptive=cf) for cf in cfgs],
               collect_traces=False, **kw)
     np.testing.assert_array_equal(np.asarray(c.exec_time),
-                                  np.asarray(d.exec_time))
+                                  np.asarray(d.exec_time[:, 0]))
     np.testing.assert_array_equal(np.asarray(c.summary["power_mean"]),
-                                  np.asarray(d.summary["power_mean"]))
+                                  np.asarray(d.summary["power_mean"][:, 0]))
 
 
 def test_policy_axis_shapes_squeeze_and_errors():
@@ -179,7 +180,7 @@ def test_policy_axis_shapes_squeeze_and_errors():
                  collect_traces=False, **kw)
     assert res2.traces is None
     assert res2.summary["power_mean"].shape == (1, 3, 2)
-    with pytest.raises(ValueError):
+    with pytest.raises(TypeError):  # the policy axis has one name
         sweep("gros", [0.1], range(2), policies=pls,
               adaptive=RLSConfig(), **kw)
     with pytest.raises(ValueError):
@@ -210,19 +211,16 @@ def test_policy_grids_share_one_compile_per_bucket():
              DutyCyclePolicy(deadband=0.05)]
     kw = dict(total_work=300.0, collect_traces=False)
     sweep("gros", [0.1], range(2), policies=pls_a, max_time=600.0, **kw)
-    info0 = sim._jit_sweep.cache_info()
-    jitted = sim._jit_sweep(sim._bucket_steps(600),
-                            ("offline_rl", "dutycycle"), False)
-    size0 = jitted._cache_size()
-    assert size0 >= 1
+    info0 = sim._flat_core.cache_info()
+    sizes0 = _engine_cache_sizes()
+    assert sum(sizes0.values()) >= 1
     # different hyperparameters, same shapes: no new trace, no new jit
     sweep("gros", [0.1], range(2), policies=pls_b, max_time=600.0, **kw)
-    info1 = sim._jit_sweep.cache_info()
-    assert info1.misses == info0.misses
-    assert jitted._cache_size() == size0
+    assert sim._flat_core.cache_info().misses == info0.misses
+    assert _engine_cache_sizes() == sizes0
     # crossing a bucket boundary compiles a fresh engine (and logs)
     sweep("gros", [0.1], range(2), policies=pls_b, max_time=1500.0, **kw)
-    assert sim._jit_sweep.cache_info().misses == info1.misses + 1
+    assert sim._flat_core.cache_info().misses == info0.misses + 1
 
 
 def test_bucket_crossing_logged_once(caplog):

@@ -21,6 +21,7 @@ from repro.core.workloads import (MAX_PHASES, DetectorConfig, Phase,
                                   detect_init, detect_step,
                                   detector_values, markov_schedule,
                                   stream_dgemm_schedule)
+from test_sim import _engine_cache_sizes
 
 STREAM = {"alpha": 3.0, "beta": 0.6}
 DGEMM = {"alpha": 0.3, "beta": 1.14, "K_L": 2.0}
@@ -268,19 +269,17 @@ def test_sweep_workload_axis_shapes_summary_and_one_compile():
     assert res.traces is None
     assert res.exec_time.shape == (2, 2, 2, 2)  # (P, E, W, S)
     assert np.isfinite(np.asarray(res.summary["progress_mean"])).all()
-    info0 = sim._jit_sweep.cache_info()
-    jitted = sim._jit_sweep(sim._bucket_steps(240), ("pi",), False,
-                            True, False)
-    size0 = jitted._cache_size()
-    assert size0 >= 1
+    info0 = sim._flat_core.cache_info()
+    sizes0 = _engine_cache_sizes()
+    assert sum(sizes0.values()) >= 1
     # different schedule values + different profile count, same bucket:
     # same lru entry, no new XLA compile for the same grid SHAPES
     sweep(("gros", "dahu"),  [0.1, 0.2], range(2),
           workloads=[markov_schedule(2, "dahu"),
                      stream_dgemm_schedule("dahu", dwell=40.0,
                                            cyclic=True)], **kw)
-    assert sim._jit_sweep.cache_info().misses == info0.misses
-    assert jitted._cache_size() == size0
+    assert sim._flat_core.cache_info().misses == info0.misses
+    assert _engine_cache_sizes() == sizes0
     # single-schedule call squeezes the W axis
     res1 = sweep("gros", [0.1], range(2), workloads=s3, **kw)
     assert res1.exec_time.shape == (1, 2)
