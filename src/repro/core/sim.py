@@ -65,6 +65,7 @@ from repro.core import executor
 from repro.core import faults as flt
 from repro.core import plane
 from repro.obs import events as evt
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.core import policies as pol
 from repro.core.adaptive import (RLSConfig, RLSState, rls_init, rls_pack,
@@ -576,7 +577,14 @@ def _scan_core(max_steps: int, collect: bool = True,
     detector / `FaultValues` / guard parameter vectors; jit separates
     the variants by pytree structure. ``n_events`` > 0 arms the flight
     recorder with that many ring slots (static: the ring shape keys the
-    jit cache; 0 keeps the recorder-free carry)."""
+    jit cache; 0 keeps the recorder-free carry).
+
+    Trace mode (``collect``) scans all ``max_steps`` periods, one trace
+    row each. Summary mode runs a `lax.while_loop` that stops once the
+    run is done: step i still takes period key i of the same split, and
+    a done run's step leaves its carry as it is, so the final carry is
+    the scan's. Under `jax.vmap` the loop runs while any run of the
+    batch is live."""
 
     def run(profile_vals, gains_vals, policy_vals, sched, det_vals,
             fvals, gvals, init: Optional[_Carry], total_work, max_time,
@@ -597,8 +605,25 @@ def _scan_core(max_steps: int, collect: bool = True,
             return c2, (out if collect else None)
 
         keys = jax.random.split(key, max_steps)
-        final, traces = jax.lax.scan(body, carry0, keys)
-        return traces, final
+        if collect:
+            final, traces = jax.lax.scan(body, carry0, keys)
+            return traces, final
+
+        # keys[i] read word by word: vmapped, step i is then two gathers
+        # from dense (runs, max_steps) arrays, where the TPU would pad a
+        # (runs, max_steps, 2) array's 2 words to a 128-word tile
+        hi, lo = keys[:, 0], keys[:, 1]
+
+        def live(s):
+            i, c = s
+            return (i < max_steps) & ~c.done
+
+        def step(s):
+            i, c = s
+            return i + 1, body(c, jnp.stack([hi[i], lo[i]]))[0]
+
+        _, final = jax.lax.while_loop(live, step, (jnp.int32(0), carry0))
+        return None, final
 
     return run
 
@@ -627,7 +652,9 @@ def _flat_core(max_steps: int, branches, collect: bool, scheduled: bool,
     vector is grid-wide, so it rides the shared argument tail
     (``guarded`` selects the variant). ``scheduled`` and ``detected``
     only key the cache: the rows' pytree structure already separates
-    those traces."""
+    those traces. In summary mode the batch's loop stops when every run
+    of it is done (`_scan_core`); `sweep` counts the steps it ran in
+    ``sweep_scan_steps_total``."""
     run = _scan_core(max_steps, collect, branches, n_events)
 
     def sweep_scan(batched, total_work, max_time, dt, summary_from, *rest):
@@ -1174,6 +1201,29 @@ def _grid_values(profs: Sequence[PlantProfile], eps: Sequence[float],
                        fault_grid)
 
 
+def _count_scan_steps(steps: np.ndarray, chunk: int, n_devices: int,
+                      max_steps: int, collect: bool) -> None:
+    """``sweep_scan_steps_total{kind}``, per engine batch (a chunk, or
+    one device's slice of it): ``run`` adds the steps its loop ran, the
+    largest final step count of its runs (trace mode scans all
+    ``max_steps``), and ``horizon`` the ``max_steps`` a scan runs. Pad
+    rows repeat their chunk's first run, as the executor pads them."""
+    n = len(steps)
+    n_chunks = -(-n // chunk)
+    padded = np.empty(n_chunks * chunk, steps.dtype)
+    padded[:n] = steps
+    padded[n:] = steps[(n_chunks - 1) * chunk]
+    batches = n_chunks * max(n_devices, 1)
+    ran = (batches * max_steps if collect
+           else int(padded.reshape(batches, -1).max(axis=1).sum()))
+    c = obs_metrics.get_registry().counter(
+        "sweep_scan_steps_total",
+        "scan engine steps per batch: run by the loop, or the horizon",
+        labelnames=("kind",))
+    c.inc(ran, kind="run")
+    c.inc(batches * max_steps, kind="horizon")
+
+
 def _sweep_impl(*args, **kwargs):
     """Shared implementation behind `sweep` / `sweep_resumable`, traced
     as one ``sweep`` span (`repro.obs.trace`); `_sweep_run` does the
@@ -1213,8 +1263,9 @@ def _sweep_run(profiles: Union[str, PlantProfile,
     """`_sweep_impl`'s work: normalizes the grid (``sweep/grid``, with
     ``sweep/keys``, ``sweep/scenario`` and ``sweep/rows``), runs its
     per-run rows through `repro.core.executor`, counts a scan-engine
-    result's runs in ``closed_loop_runs_total{path="scan"}``, and
-    assembles the result (``sweep/summary``).
+    result's runs in ``closed_loop_runs_total{path="scan"}`` and its
+    steps in ``sweep_scan_steps_total``, and assembles the result
+    (``sweep/summary``).
     Returns (SweepResult | None, ExecState | None)."""
     tracer = obs_trace.get_tracer()
     with tracer.span("sweep/grid"):
@@ -1337,6 +1388,9 @@ def _sweep_run(profiles: Union[str, PlantProfile,
         # the kernel op counts its own runs (native, interpret, ref)
         from repro.kernels.closed_loop.ops import runs_counter
         runs_counter().inc(n_runs, path="scan")
+        _count_scan_steps(final.steps, exec_state.chunk,
+                          len(executor.resolve_devices(devices)),
+                          max_steps, collect_traces)
     with tracer.span("sweep/summary"):
         if backend == "pallas":
             final = _carry_from_kernel_final(final)

@@ -24,8 +24,10 @@ from repro.core.adaptive import (RLSAdapter, RLSConfig, rls_init,
 from repro.core.controller import PIGains
 from repro.core.plane import plane_step
 from repro.core.plant import PROFILES
-from repro.core.policies import PIPolicy
+from repro.core.policies import DutyCyclePolicy, PIPolicy
 from repro.core.sim import simulate_closed_loop, sweep
+from repro.core.workloads.detect import DetectorConfig
+from repro.core.workloads.schedule import Phase, PhaseSchedule
 
 KW = dict(total_work=400.0, max_time=300.0)
 
@@ -217,6 +219,91 @@ def test_chunked_faulted_guarded_sweep_equals_one_shot(monkeypatch):
                                     maxulp=MAX_ULP)
     assert (np.asarray(one.energy)[..., 1]
             != np.asarray(moved.energy)[..., 1]).all()
+
+
+# ---------------------------------------------------------------------------
+# summary mode's early stop: the while loop ends where the scan's steps
+# stop changing anything
+# ---------------------------------------------------------------------------
+
+_BLACKOUT = flt.FaultSchedule((
+    flt.FaultWindow("hb_dropout", 30.0, 20.0, p1=1.0),
+    flt.FaultWindow("meter_freeze", 30.0, 20.0)), period=150.0)
+# a tenant down for the whole horizon never reaches total_work
+_DOWN = flt.FaultSchedule((flt.FaultWindow("crash", 0.0, 1000.0),))
+_RUN_FIELDS = ("exec_time", "energy", "work", "n_steps", "detections",
+               "guard_state")
+
+
+def _phased_grid(faults, chunk_size=None, collect_traces=False):
+    """A phased, faulted, guarded, detector, mixed-policy grid over two
+    plants whose runs finish ~100 steps apart."""
+    phases = PhaseSchedule((
+        Phase(50.0, scale=(("alpha", 3.0), ("beta", 0.6))),
+        Phase(50.0, scale=(("alpha", 0.3), ("beta", 1.14)))), cyclic=True)
+    return sweep(["gros", "yeti"], [0.1, 0.3], range(3),
+                 total_work=3000.0, max_time=400.0,
+                 policies=[PIPolicy(), PIPolicy(adaptive=RLSConfig()),
+                           DutyCyclePolicy()],
+                 workloads=phases, detector=DetectorConfig(),
+                 faults=faults, guard=flt.GuardConfig(),
+                 chunk_size=chunk_size, collect_traces=collect_traces)
+
+
+@pytest.mark.parametrize("faults,chunk_size", [
+    (_BLACKOUT, None),          # one batch
+    (_BLACKOUT, 5),             # runs finish unevenly across chunks
+    ([_BLACKOUT, _DOWN], None),  # half the runs last to max_time
+], ids=["one_batch", "chunks", "to_max_time"])
+def test_summary_loop_stops_with_the_scans_final_carry(faults, chunk_size):
+    """Summary mode's while loop stops when every run of its batch is
+    done; trace mode scans every period. Their final carries agree bit
+    for bit: a done run's step leaves its carry unchanged."""
+    lean = _phased_grid(faults, chunk_size)
+    full = _phased_grid(faults, collect_traces=True)
+    assert lean.traces is None and full.traces is not None
+    for f in _RUN_FIELDS:
+        np.testing.assert_array_equal(np.asarray(getattr(lean, f)),
+                                      np.asarray(getattr(full, f)),
+                                      err_msg=f)
+    for k in full.summary:
+        np.testing.assert_array_equal(np.asarray(lean.summary[k]),
+                                      np.asarray(full.summary[k]),
+                                      err_msg=k)
+    steps = np.asarray(lean.n_steps)
+    assert steps.max() < 400 if faults is _BLACKOUT else steps.max() == 400
+
+
+def test_scan_steps_counter_counts_each_batch():
+    """``sweep_scan_steps_total{kind="run"}`` rises by each chunk's
+    largest ``n_steps`` (the loop's steps), ``{kind="horizon"}`` by
+    chunks x ``max_steps``; trace mode runs the whole horizon. Chunked
+    and unchunked summary sweeps agree."""
+    from repro.core import sim
+    from repro.obs import metrics
+    c = metrics.get_registry().counter("sweep_scan_steps_total",
+                                       labelnames=("kind",))
+
+    def counted(**kw):
+        before = c.value(kind="run"), c.value(kind="horizon")
+        res = _phased_grid(_BLACKOUT, **kw)
+        return res, (c.value(kind="run") - before[0],
+                     c.value(kind="horizon") - before[1])
+
+    max_steps = sim._bucket_steps(400)
+    one, (run1, hor1) = counted()
+    steps = np.asarray(one.n_steps).reshape(-1)
+    assert (run1, hor1) == (steps.max(), max_steps)
+    ch, (run5, hor5) = counted(chunk_size=5)
+    n_chunks = -(-steps.size // 5)
+    assert run5 == sum(steps[i:i + 5].max()
+                       for i in range(0, steps.size, 5))
+    assert hor5 == n_chunks * max_steps and run5 < hor5
+    _assert_same_runs(one, ch)
+    np.testing.assert_array_equal(np.asarray(one.n_steps),
+                                  np.asarray(ch.n_steps))
+    _, (run_t, hor_t) = counted(collect_traces=True)
+    assert run_t == hor_t == max_steps
 
 
 # ---------------------------------------------------------------------------

@@ -689,3 +689,20 @@ def test_sweep_grid_span_makes_no_device_call(monkeypatch, grid, n_runs,
         assert isinstance(seen["shared"][4], np.ndarray)  # guard vector
     else:
         assert len(seen["shared"]) == 4
+
+
+@pytest.mark.parametrize("collect", [False, True], ids=["summary", "trace"])
+def test_scan_steps_counted_per_device_slice_with_pad_rows(monkeypatch,
+                                                           collect):
+    """Each device's slice of a chunk is its own loop: it ran its runs'
+    largest step count, and a pad row (the executor repeats the chunk's
+    first run) counts as that run. Trace mode scans the horizon."""
+    from repro.obs import metrics
+    reg = metrics.MetricsRegistry()
+    monkeypatch.setattr(metrics, "get_registry", lambda: reg)
+    steps = np.array([5, 1, 2, 9, 3, 4, 7], np.int32)
+    # chunks of 4 on 2 devices: [5, 1] [2, 9] | [3, 4] [7, pad 3]
+    sim._count_scan_steps(steps, 4, 2, 256, collect)
+    c = reg.counter("sweep_scan_steps_total", labelnames=("kind",))
+    assert c.value(kind="horizon") == 4 * 256
+    assert c.value(kind="run") == (4 * 256 if collect else 5 + 9 + 4 + 7)
